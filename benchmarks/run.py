@@ -115,6 +115,9 @@ def main(only: str | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run only entries whose name contains this substring")

@@ -61,6 +61,7 @@ from repro.core import spike_ops
 from repro.core.rsnn import RSNNConfig
 from repro.data.featurize import AsyncFeaturizer
 from repro.data.synthetic import SpeechDataConfig, TimitLikeStream
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import backends
 from repro.serving.sharded import ShardedStreamLoop
 from repro.serving.stream import (CompiledRSNN, EngineConfig, StreamLoop,
@@ -207,7 +208,9 @@ def main():
           f"{loop.steps} engine steps ({args.slots} slots, "
           f"pipeline depth {args.pipeline_depth}, "
           f"{loop.host_syncs / frames:.3f} host syncs/frame)")
-    print(f"  {frames / dt:.0f} frames/s on CPU -> "
+    dev = jax.devices()[0]
+    print(f"  {frames / dt:.0f} frames/s on {dev.platform} "
+          f"({dev.device_kind}) -> "
           f"{frames / dt / C.FRAMES_PER_SECOND:.1f} concurrent real-time streams")
     prof = loop.sparsity_profile()
     print(f"  measured sparsity: input bits {1 - prof.input_bit_density:.0%}, "
@@ -226,4 +229,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -22,9 +22,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _unpack_block(packed):
-    """(bK//2, bN) int8 -> (bK, bN) f32 in [-8, 7]."""
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
+    """(bK//2, bN) int8 -> (bK, bN) f32 in [-8, 7] (low nibble = even row).
+
+    The byte widens to int32 before the shift: Mosaic cannot shift an
+    int8 vector."""
+    p = packed.astype(jnp.int32)
+    lo = p & 0xF
+    hi = (p >> 4) & 0xF
     lo = jnp.where(lo >= 8, lo - 16, lo).astype(jnp.float32)
     hi = jnp.where(hi >= 8, hi - 16, hi).astype(jnp.float32)
     k2, bn = packed.shape

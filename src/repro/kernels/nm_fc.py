@@ -4,17 +4,18 @@ Consumes ``core.layouts.nm.NMGroupPacked`` directly — the regular-sparsity
 deployment layout of N:M-pruned weights (fixed ``n`` survivors per ``m``
 input rows, value nibble + in-group offset nibble in one byte, no index
 padding).  Compared to ``kernels/sparse_fc.py`` (padded CSC), the weight
-tile carries *half* the VMEM traffic at equal nnz — one int8 byte per
+tile carries *half* the HBM->VMEM traffic at equal nnz — one int8 byte per
 entry instead of an int32 index plus a float32 value — and the global row
 ids are reconstructed in VMEM from the entry position (``e // n``) and the
 stored offset, the software analogue of the accelerator's implicit-index
-regular-sparsity fetch.
+regular-sparsity fetch.  As in ``sparse_fc``, the tile expands to its dense
+integer codes in VMEM and feeds one MXU dot.
 
 Merged-spike input path (paper §II-D2): the kernel accepts the raw
-``(TS, B, H)`` spike trains and sums them over TS in VMEM before the
-gather — one pass serves every time step.  The gather/FMA/sum ordering
-mirrors ``sparse_fc`` exactly, so the same mask packed as CSC or N:M-group
-executes bit-identically (tests/test_nm_fc.py).
+``(TS, B, H)`` spike trains and sums them over TS in VMEM first — one
+pass serves every time step.  The integer accumulate is exact, so the
+same mask packed as CSC or N:M-group executes bit-identically
+(tests/test_nm_fc.py).
 """
 
 from __future__ import annotations
@@ -25,32 +26,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sparse_fc import _fit_block
 
-def _fit_block(dim: int, block: int) -> int:
-    """Largest tile <= block that divides dim (grid must tile exactly; the
-    paper's fc_dim=1920 is not a power-of-2 multiple)."""
-    block = min(block, dim)
-    while dim % block:
-        block -= 1
-    return block
+
+def _expand_nm(p_ref, rows: int, nm_n: int, nm_m: int) -> jax.Array:
+    """Group-packed N:M operand (E, N) int8 -> dense (rows, N) int4 codes.
+
+    Entry ``e`` belongs to row group ``e // n``; its global row is
+    ``group * m + offset`` (the high nibble), its value the low nibble;
+    one entry per (static) iteration.  Tail pad slots carry value 0, so
+    every sum here is exact.
+    """
+    # widen before the shifts (Mosaic cannot shift an int8 vector); the
+    # loop is static because Mosaic loads int8 rows only 8-aligned
+    p = p_ref[...].astype(jnp.int32)
+    val = p & 0xF
+    val = jnp.where(val >= 8, val - 16, val).astype(jnp.float32)
+    row = (p >> 4) & 0xF
+    iota = jax.lax.broadcasted_iota(jnp.int32, (rows, p.shape[1]), 0)
+    w = jnp.zeros((rows, p.shape[1]), jnp.float32)
+    for e in range(p.shape[0]):
+        hit = iota == (e // nm_n) * nm_m + row[e:e + 1]
+        w = w + jnp.where(hit, val[e:e + 1], 0.0)
+    return w
 
 
 def _nm_fc_kernel(s_ref, p_ref, scale_ref, o_ref, *, n, m):
     # merge time steps in VMEM: one pass for all TS
     x = s_ref[...].astype(jnp.float32).sum(axis=0)  # (bB, H)
-    p = p_ref[...]  # (E, bN) int8: value nibble | offset nibble << 4
-    val = (p & 0xF).astype(jnp.int8)
-    val = jnp.where(val >= 8, val - 16, val).astype(jnp.float32)
-    off = ((p >> 4) & 0xF).astype(jnp.int32)  # in-group row offset
-    e, bn = p.shape
-    # implicit indexing: entry e of any column belongs to row group e // n
-    group = jax.lax.broadcasted_iota(jnp.int32, (e, bn), 0) // n
-    idx = group * m + off  # (E, bN) global rows
-    bb = x.shape[0]
-    # gather surviving rows per output channel; tail pad slots carry value 0
-    # so they contribute nothing (no mask needed)
-    gathered = jnp.take(x, idx.reshape(-1), axis=1).reshape(bb, e, bn)
-    acc = (gathered * val[None]).sum(axis=1)  # (bB, bN)
+    w = _expand_nm(p_ref, x.shape[1], n, m)  # (H, bN) int4 codes
+    acc = jnp.dot(x, w, preferred_element_type=jnp.float32)
     o_ref[...] = (acc * scale_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
@@ -64,15 +69,15 @@ def nm_fc(spikes_ts: jax.Array, packed: jax.Array, scale: jax.Array, *,
     spikes_ts: (TS, B, H) binary spike trains (a pre-merged (B, H) input is
     also accepted); packed: (groups * n, N) int8 from
     ``core.layouts.nm.NMGroupPacked``; scale: (N,) or (1, N) per-channel.
-    Accumulation order matches ``layouts.nm.nm_matmul`` (sum over the
-    entry axis), so results agree with the dense matmul to float tolerance
-    and with the padded-CSC path bitwise for the same mask.
+    The integer accumulate is exact, then scaled — the same two steps as
+    ``layouts.nm.nm_matmul`` — so results agree bitwise with it and with
+    the padded-CSC path for the same mask.
     """
     if spikes_ts.ndim == 2:
         spikes_ts = spikes_ts[None]
     ts, b, h = spikes_ts.shape
     e, nn = packed.shape
-    bb, bn = _fit_block(b, block_b), _fit_block(nn, block_n)
+    bb, bn = _fit_block(b, block_b, 8), _fit_block(nn, block_n, 128)
     grid = (b // bb, nn // bn)
     return pl.pallas_call(
         functools.partial(_nm_fc_kernel, n=n, m=m),

@@ -1,7 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python, validating TPU semantics; on TPU they compile to Mosaic.
+On a TPU the kernels compile to Mosaic; on any other backend (the CPU test
+suite) they execute in interpret mode, which checks their semantics but
+not what the chip's compiler accepts (``tests/test_tpu_compile.py`` does).
 """
 
 from __future__ import annotations

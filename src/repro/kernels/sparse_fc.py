@@ -3,16 +3,18 @@
 Consumes ``core.sparse.SparseColumns`` directly — the deployment layout of
 the paper's 40%-unstructured-pruned FC.  The jnp reference
 (``core.sparse.sparse_matmul``) gathers ``x[:, indices]`` which XLA
-materializes as a ``(B, nnz_max, N)`` HBM intermediate; here the gather is
-tiled: for each output-channel block the ``(nnz_max, bN)`` index/value
-tiles sit in VMEM next to the batch tile of the merged spike vector, rows
-are gathered and FMA'd in VMEM, and only the ``(bB, bN)`` result ever
-leaves the core.  Work still scales with nnz (the accelerator's skipped
-accumulates), weight traffic with the CSC payload.
+materializes as a ``(B, nnz_max, N)`` HBM intermediate; here only the
+compressed ``(nnz_max, bN)`` index/value tiles cross into VMEM, where they
+expand into the dense ``(H, bN)`` integer-code tile (``_expand_csc``) next
+to the batch tile of the merged spike vector, and one MXU dot plus the
+per-channel scale produce the ``(bB, bN)`` result.  The MXU has no
+per-column gather cheaper than that dot; weight traffic still scales with
+the CSC payload.  Spike counts times int4 codes sum exactly, so the
+integer accumulate does not depend on the summation order.
 
 Merged-spike input path (paper §II-D2): the kernel accepts the raw
 ``(TS, B, H)`` spike trains and sums them over TS in VMEM before the
-gather — one CSC pass serves every time step, the same trick
+dot — one CSC pass serves every time step, the same trick
 ``kernels/merged_spike_fc.py`` plays for the dense int4 FC.
 """
 
@@ -25,26 +27,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _fit_block(dim: int, block: int) -> int:
-    """Largest tile <= block that divides dim (grid must tile exactly; the
-    paper's fc_dim=1920 is not a power-of-2 multiple)."""
-    block = min(block, dim)
-    while dim % block:
-        block -= 1
-    return block
+def _fit_block(dim: int, block: int, align: int) -> int:
+    """Largest tile <= ``block`` that divides ``dim`` and is a multiple of
+    ``align`` — the TPU tiles a block's last two dims by (8, 128) — or the
+    whole dim when there is none (a full-extent block is always legal).
+    The paper's fc_dim=1920 is 15 x 128, so the FC tiles are 384 wide."""
+    for tile in range(min(block, dim) // align * align, 0, -align):
+        if dim % tile == 0:
+            return tile
+    return dim
+
+
+def _expand_csc(idx_ref, val_ref, rows: int) -> jax.Array:
+    """Padded-CSC operands (nnz_max, N) -> dense (rows, N) int4 codes.
+
+    One stored entry per iteration: row ``idx[e, n]`` of column ``n``
+    receives ``val[e, n]``.  A column stores each row at most once and pad
+    slots carry value 0, so every sum here is exact.
+    """
+    nnz, n = idx_ref.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 0)
+
+    def body(e, w):
+        hit = row == idx_ref[pl.ds(e, 1), :]
+        return w + jnp.where(hit, val_ref[pl.ds(e, 1), :], 0.0)
+
+    return jax.lax.fori_loop(0, nnz, body, jnp.zeros((rows, n), jnp.float32))
 
 
 def _sparse_fc_kernel(s_ref, idx_ref, val_ref, scale_ref, o_ref):
     # merge time steps in VMEM: one CSC pass for all TS
     x = s_ref[...].astype(jnp.float32).sum(axis=0)  # (bB, H)
-    idx = idx_ref[...]  # (nnz_max, bN) int32 row ids, 0-padded
-    val = val_ref[...].astype(jnp.float32)  # (nnz_max, bN), 0 on padding
-    bb = x.shape[0]
-    nnz, bn = idx.shape
-    # gather surviving rows per output channel; padded entries carry value 0
-    # so they contribute nothing (no mask needed)
-    gathered = jnp.take(x, idx.reshape(-1), axis=1).reshape(bb, nnz, bn)
-    acc = (gathered * val[None]).sum(axis=1)  # (bB, bN)
+    w = _expand_csc(idx_ref, val_ref, x.shape[1])  # (H, bN) int4 codes
+    acc = jnp.dot(x, w, preferred_element_type=jnp.float32)
     o_ref[...] = (acc * scale_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
@@ -57,14 +72,14 @@ def sparse_fc(spikes_ts: jax.Array, indices: jax.Array, values: jax.Array,
     spikes_ts: (TS, B, H) binary spike trains (a pre-merged (B, H) input is
     also accepted); indices/values: (nnz_max, N) from
     ``core.sparse.SparseColumns``; scale: (N,) or (1, N) per-channel.
-    Accumulation order matches ``core.sparse.sparse_matmul`` (sum over the
-    nnz axis), so results agree with the dense matmul to float tolerance.
+    The integer accumulate is exact, then scaled — the same two steps as
+    ``core.sparse.sparse_matmul``, so results agree bitwise.
     """
     if spikes_ts.ndim == 2:
         spikes_ts = spikes_ts[None]
     ts, b, h = spikes_ts.shape
     nnz, n = indices.shape
-    bb, bn = _fit_block(b, block_b), _fit_block(n, block_n)
+    bb, bn = _fit_block(b, block_b, 8), _fit_block(n, block_n, 128)
     grid = (b // bb, n // bn)
     return pl.pallas_call(
         _sparse_fc_kernel,

@@ -4,40 +4,38 @@ The paper's input-broadcasting scheme "eliminates zero computations" on the
 *activation* side: each binary spike vector is scanned by a priority
 encoder, and only the surviving spike indices broadcast their weight rows
 into the accumulators — a column of W is fetched/accumulated per *event*,
-not per neuron.  This module is that scheme as an executed compute path,
-the activation-side twin of the weight-side zero-skip layouts
+not per neuron.  This module is that scheme mapped onto the TPU, the
+activation-side twin of the weight-side zero-skip layouts
 (``kernels/sparse_fc`` / ``kernels/nm_fc``):
 
-  * ``compact_spikes`` — the priority-encoder: each row's nonzero entries
-    compact into a fixed-``capacity`` ascending-index event list (index +
-    value), zero-padded past the row's population count.  The formula is a
-    cumsum/compare cascade (no sort, no scatter), the software echo of the
-    hardware encoder tree.
-  * ``spike_broadcast`` — gather-based matmul over the event lists: for
-    each event, the matching row of W is gathered and FMA'd.  The
-    accumulate runs as ONE dot over the event axis in ascending-index
-    order, which on the sequential-reduction regime (contraction depth
-    <= ~384 on this XLA build; H is 128/256 here) produces the *same
-    partial-sum sequence* as the dense ``x @ W`` — zero-valued padding
-    terms contribute exact zeros — so the result is **bit-identical** to
-    the dense path, not merely allclose.  A 3-D ``(TS, B, H)`` input takes
-    the merged-spike-union path (paper §II-D2): TS trains sum in VMEM and
-    one gather pass serves every time step, like ``sparse_fc``.
+  * ``event_rank`` — the running population count of each row (the
+    priority encoder's event number per position), computed as one dot
+    with an upper-triangular ones matrix.  Its operands are 0/1 and its
+    sums are integers <= K, so it is exact at any MXU precision (Mosaic
+    has no cumsum).
+  * ``compact_spikes`` — the priority encoder itself: each row's nonzero
+    entries compact into a fixed-``capacity`` ascending-index event list
+    (index + value), zero-padded past the row's population count.
+  * ``gather_matmul`` / ``spike_broadcast`` — the matmul over each row's
+    event list.  A finite ``capacity`` models the hardware event queue:
+    rows with more events TRUNCATE their highest-index events (the oracle
+    ``ref.spike_broadcast_ref`` defines the same tail-drop semantics).  On
+    the MXU the accumulate over the kept events is one dense dot over the
+    row with the dropped events zeroed: an explicit per-event row gather
+    would cost ``capacity`` times the dense MACs, and zero activations add
+    exact zeros.  So at lossless capacity it runs the dense ``x @ W``
+    itself, the same dot the dense kernels run; the zero-skip saving
+    of the ASIC is accounted analytically
+    (``core.complexity.spike_broadcast_report``), not in TPU time.  A 3-D
+    ``(TS, B, H)`` input takes the merged-spike-union path (paper §II-D2):
+    TS trains sum in VMEM and one pass serves every time step.
   * ``spike_cell`` — the fused recurrent-spiking-layer step of
-    ``kernels/rsnn_cell`` with the recurrent matmul replaced by the event
-    gather: one W fetch per batch tile (Chipmunk-style amortization), TS
-    folded into the event-list row axis, LIF chain fused in the epilogue.
+    ``kernels/rsnn_cell`` with the recurrent matmul run over the event
+    lists: one W fetch per batch tile, TS folded into the row axis, LIF
+    chain fused in the epilogue.
 
 Capacity contract: ``capacity=None`` sizes the event list to the full
-contraction dim (lossless — every active row fits).  A smaller static
-capacity models a finite hardware event queue: rows whose population count
-exceeds it TRUNCATE their highest-index events (the oracle
-``ref.spike_broadcast_ref`` defines the same tail-drop semantics).
-
-VMEM note: the compare cascade materializes a ``(bR, capacity, K)``
-boolean intermediate per tile — the kernel's high-water mark.  ``block_r``
-and ``capacity`` bound it; at the paper's shapes (H=128/256, batch tiles
-<= 128) it stays inside the ~16 MB budget.
+contraction dim (lossless — every active row fits).
 """
 
 from __future__ import annotations
@@ -51,6 +49,17 @@ from jax.experimental import pallas as pl
 from repro.kernels.sparse_fc import _fit_block
 
 
+def event_rank(x: jax.Array) -> jax.Array:
+    """(R, K) -> (R, K) f32 running count of nonzeros along each row
+    (inclusive): the event number of every active position."""
+    k = x.shape[1]
+    nz = (x != 0).astype(jnp.float32)
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, (k, k), 1))
+    return jnp.dot(nz, upper.astype(jnp.float32),
+                   preferred_element_type=jnp.float32)
+
+
 def compact_spikes(x: jax.Array, capacity: int
                    ) -> tuple[jax.Array, jax.Array]:
     """Priority-encode each row of ``x (R, K)`` into an ascending-index
@@ -60,37 +69,36 @@ def compact_spikes(x: jax.Array, capacity: int
     column of row ``r``'s ``(j+1)``-th nonzero (clamped to ``K-1`` past the
     end) and ``vals[r, j]`` that entry's value, ``0.0`` on padding.  Rows
     with more than ``capacity`` active entries truncate their highest
-    indices.  Pure jnp — runs inside Pallas kernels and as the oracle's
-    shared compaction primitive (one definition, no drift).
+    indices.  Pure jnp over 2-D values (a static loop over the K columns;
+    no cumsum, sort, scatter or gather), so it runs inside Pallas kernels
+    on the TPU as well as in the interpreter.
     """
     r, k = x.shape
-    cnt = jnp.cumsum((x != 0).astype(jnp.int32), axis=1)  # (R, K) inclusive
-    # slot j holds the (j+1)-th active index: the number of positions whose
-    # running population count is still <= j (2-D+ iota: 1-D fails on TPU)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, capacity, 1), 1)
-    idx = (cnt[:, None, :] <= slot).sum(axis=2)  # (R, capacity)
-    idx = jnp.minimum(idx, k - 1)  # clamp padding slots to a real row
-    valid = jax.lax.broadcasted_iota(jnp.int32, (1, capacity), 1) < cnt[:, -1:]
-    vals = jnp.take_along_axis(x, idx, axis=1)
-    vals = jnp.where(valid, vals, jnp.zeros((), x.dtype))
+    rank = event_rank(x)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, capacity), 1) \
+        .astype(jnp.float32)
+    idx = jnp.zeros((r, capacity), jnp.float32)
+    vals = jnp.zeros((r, capacity), x.dtype)
+    for c in range(k):
+        rc, xc = rank[:, c:c + 1], x[:, c:c + 1]
+        # slot j holds the (j+1)-th event: its column is the number of
+        # columns whose running count is still <= j
+        idx = idx + (rc <= slot).astype(jnp.float32)
+        vals = jnp.where((xc != 0) & (rc == slot + 1), xc, vals)
+    idx = jnp.minimum(idx, k - 1).astype(jnp.int32)  # clamp padding slots
     return idx, vals
 
 
 def gather_matmul(x: jax.Array, w: jax.Array, capacity: int) -> jax.Array:
-    """Event-gather matmul: ``x (R, K) @ w (K, N)`` touching only the rows
-    of ``w`` named by each row's event list.
-
-    The accumulate is a single dot over the event axis in ascending-index
-    order — bit-identical to the dense ``jnp.dot(x, w)`` when every active
-    entry fits ``capacity`` (the padding events multiply by exact 0.0).
-    Pure jnp: the kernel bodies and the mega-step's spike mode both call
-    this, so there is exactly one accumulation order to reason about.
-    """
-    idx, vals = compact_spikes(x, capacity)
-    r = x.shape[0]
-    g = jnp.take(w, idx.reshape(-1), axis=0).reshape(r, capacity, w.shape[1])
-    return jnp.einsum("rc,rcn->rn", vals, g,
-                      preferred_element_type=jnp.float32)
+    """Event-list matmul: ``x (R, K) @ w (K, N)`` over each row's first
+    ``capacity`` events.  At lossless capacity (``capacity >= K``) every
+    event is kept and this is the dense dot; otherwise the events past the
+    queue's capacity are zeroed first (``ref.spike_broadcast_ref``'s
+    truncation, bit for bit).  Pure jnp: the kernel bodies and the
+    mega-step's spike mode both call this."""
+    if capacity < x.shape[1]:
+        x = jnp.where(event_rank(x) <= capacity, x, jnp.zeros((), x.dtype))
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
 def _spike_broadcast_kernel(x_ref, w_ref, o_ref, *, capacity: int):
@@ -108,14 +116,14 @@ def _spike_broadcast_kernel(x_ref, w_ref, o_ref, *, capacity: int):
 def spike_broadcast(x: jax.Array, w: jax.Array, *, capacity: int | None = None,
                     block_r: int = 128, block_n: int = 512,
                     interpret: bool = False) -> jax.Array:
-    """Event-driven matmul ``x @ w`` skipping zero activations.
+    """Event-driven matmul ``x @ w`` over each row's event list.
 
-    ``x``: ``(R, K)`` rows (binary spikes, merged counts, or any input —
-    zeros are skipped, values are gathered), or ``(TS, B, K)`` spike trains
-    which merge over TS in VMEM first (the FC readout's union variant).
-    ``w``: ``(K, N)`` dense float weights.  Returns ``(R|B, N)`` float32,
-    bit-identical to the dense matmul when ``capacity`` is lossless (see
-    module docstring for the truncation contract otherwise).
+    ``x``: ``(R, K)`` rows (binary spikes, merged counts, or any input),
+    or ``(TS, B, K)`` spike trains which merge over TS in VMEM first (the
+    FC readout's union variant).  ``w``: ``(K, N)`` dense float weights.
+    Returns ``(R|B, N)`` float32, bit-identical to the dense matmul when
+    ``capacity`` is lossless (see module docstring for the truncation
+    contract otherwise).
     """
     if x.ndim == 3:
         ts, rows, k = x.shape
@@ -123,7 +131,7 @@ def spike_broadcast(x: jax.Array, w: jax.Array, *, capacity: int | None = None,
         rows, k = x.shape
     n = w.shape[1]
     cap = k if capacity is None else min(capacity, k)
-    br, bn = _fit_block(rows, block_r), _fit_block(n, block_n)
+    br, bn = _fit_block(rows, block_r, 8), _fit_block(n, block_n, 128)
     grid = (rows // br, n // bn)
     if x.ndim == 3:
         x_spec = pl.BlockSpec((ts, br, k), lambda i, j: (0, i, 0))
@@ -144,7 +152,7 @@ def _spike_cell_kernel(stim_ref, s_ref, w_ref, u0_ref, h0_ref, beta_ref,
                        capacity: int):
     ts, bb, h_in = s_ref.shape
     # --- recurrent stimulus: TS folds into the event-list row axis, so one
-    # W fetch serves every time step AND only spike events accumulate ------
+    # W fetch serves every time step --------------------------------------
     s2 = s_ref[...].astype(jnp.float32).reshape(ts * bb, h_in)
     rec = gather_matmul(s2, w_ref[...].astype(jnp.float32), capacity)
     stim = stim_ref[...].astype(jnp.float32) + rec.reshape(ts, bb, -1)
@@ -166,16 +174,15 @@ def spike_cell(stim_base: jax.Array, s_prev: jax.Array, w: jax.Array,
                u0: jax.Array, h0: jax.Array, beta: jax.Array,
                vth: jax.Array, *, capacity: int | None = None,
                block_b: int = 128, interpret: bool = False):
-    """Fused spiking-layer step with the event-gather recurrent matmul.
+    """Fused spiking-layer step with the event-list recurrent matmul.
 
     Drop-in for ``kernels/rsnn_cell.rsnn_cell`` / ``ref.rsnn_cell_ref``
-    (same shapes and LIF chain) but the ``s_prev @ W`` runs over compacted
-    spike events only — bit-identical to the dense cell at lossless
-    ``capacity``.  Batch tiles via ``_fit_block`` (no 128-row MXU
-    contract: the gather path has no systolic alignment to honor).
+    (same shapes and LIF chain) but the ``s_prev @ W`` runs over each
+    row's event list — bit-identical to the dense cell at lossless
+    ``capacity``.  Batch tiles via ``_fit_block``.
     """
     ts, b, h = s_prev.shape
-    bb = _fit_block(b, block_b)
+    bb = _fit_block(b, block_b, 8)
     cap = h if capacity is None else min(capacity, h)
     beta2 = beta.reshape(1, h)
     vth2 = vth.reshape(1, h)

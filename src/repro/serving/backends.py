@@ -70,6 +70,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import layouts, spike_ops
 from repro.core.lif import LIFState
@@ -97,6 +98,7 @@ class BackendContext:
     sparse: dict  # name -> layout tensor (SparseColumns / NMGroupPacked)
     delta_threshold: float = 0.0  # delta backend's |x_t - x_prev| gate
     spike_capacity: int | None = None  # event-list slots (None = lossless)
+    mesh: Mesh | None = None  # serving mesh whose "data" axis shards slots
 
 
 class OpTable(NamedTuple):
@@ -401,17 +403,34 @@ def _fused_table(ctx: BackendContext, *, spike: bool) -> OpTable:
     else:
         fc_mode, fcargs, statics = layouts.layout_of(fct).megastep_fc(fct)
 
+    def kernel(x_chunk, s0, u0, h0, s1, u1, h1, lif, wargs, fcargs):
+        return ops.megastep(
+            x_chunk, s0, u0, h0, s1, u1, h1,
+            lif["beta0"], lif["vth0"], lif["beta1"], lif["vth1"],
+            wargs, fcargs, precision=ctx.precision, fc_mode=fc_mode,
+            input_bits=cfg.input_bits, spike=spike, **statics)
+
+    if ctx.mesh is not None:
+        # a Mosaic kernel cannot be partitioned by the compiler: every
+        # device runs it on its own slot shard (slots are independent),
+        # with the weights and LIF constants replicated
+        slots, rows, rep = P(None, "data"), P("data"), P()
+        kernel = jax.shard_map(
+            kernel, mesh=ctx.mesh,
+            in_specs=(slots, slots, rows, rows, slots, rows, rows, rep, rep,
+                      rep),
+            out_specs=(slots, rows, slots, rows, slots,
+                       P(None, None, "data"), P(None, None, "data"), slots,
+                       slots),
+            check_vma=False)
+
     def megastep(state: RSNNState, x_chunk: jax.Array, lif: dict):
         # chunk-native: x_chunk is (F, B, input_dim) and maps onto the
         # kernel's frame-chunk grid axis — F frames advance in ONE Pallas
         # dispatch with the weights staying VMEM-resident across the chunk
-        outs = ops.megastep(
+        s0, u0, s1, u1, logits, sp0, sp1, union, bits = kernel(
             x_chunk, state.h0, state.lif0.u, state.lif0.spike,
-            state.h1, state.lif1.u, state.lif1.spike,
-            lif["beta0"], lif["vth0"], lif["beta1"], lif["vth1"],
-            wargs, fcargs, precision=ctx.precision, fc_mode=fc_mode,
-            input_bits=cfg.input_bits, spike=spike, **statics)
-        s0, u0, s1, u1, logits, sp0, sp1, union, bits = outs
+            state.h1, state.lif1.u, state.lif1.spike, lif, wargs, fcargs)
         new_state = RSNNState(h0=s0, h1=s1,
                               lif0=LIFState(u=u0, spike=s0[-1]),
                               lif1=LIFState(u=u1, spike=s1[-1]))

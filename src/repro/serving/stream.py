@@ -143,6 +143,14 @@ class EngineConfig:
         return self.sparse_fc or self.backend == "sparse"
 
 
+# Every dot of the served model runs at full f32 precision, XLA's and the
+# kernels' alike.  The TPU's default f32 matmul is one bf16 pass, which
+# rounds each weight to 8 mantissa bits; spikes are thresholds of the
+# membrane those weights sum to, and a flipped spike carries forward
+# through the recurrence.
+MATMUL_PRECISION = "highest"
+
+
 def calibrate_input_scale(features: jax.Array, bits: int = 8) -> jax.Array:
     """Static input quantization scale from calibration audio (max-abs)."""
     return spike_ops.quantize_input(features, bits)[1]
@@ -315,11 +323,14 @@ class CompiledRSNN:
         """``jax.device_put`` every deployed array (dense/quant/CSC weights,
         LIF constants, input scale) with ``sharding`` — e.g. replicated over
         a serving mesh — then re-resolve the op table and re-jit so the
-        compiled steps capture the placed copies."""
+        compiled steps capture the placed copies.  A ``NamedSharding``'s
+        mesh goes to the op table too: the mega-step kernel then runs on
+        each device over that device's slot shard."""
         put = lambda tree: jax.device_put(tree, sharding)  # noqa: E731
         self._ctx = dataclasses.replace(
             self._ctx, dense=put(self._ctx.dense), quant=put(self._ctx.quant),
-            sparse=put(self._ctx.sparse))
+            sparse=put(self._ctx.sparse),
+            mesh=getattr(sharding, "mesh", None))
         self.ops = backends.resolve(self.engine.backend, self._ctx)
         self._w = self._ctx.dense
         self._lif = put(self._lif)
@@ -418,10 +429,11 @@ class CompiledRSNN:
             # they all inherit the collapsed dispatch.  The binding is
             # chunk-native — (F, B, input_dim) in, leading frame axis out —
             # and one frame is its F=1 special case.
-            state, logits, aux = self.ops.megastep(state, x_t[None],
-                                                   self._lif)
+            state, logits, aux = self._chunk_step(state, x_t[None])
             return state, logits[0], {k: v[0] for k, v in aux.items()}
-        if self.ops.delta_gate is not None:
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            if self.ops.delta_gate is None:
+                return self._compose_step(state, x_t)
             # delta-temporal gating (EdgeDRNN): propagate only elements
             # with |x_t - x_prev| > threshold, hold the rest, and reuse
             # the cached L0 pre-activation for slots with no delta; the
@@ -431,12 +443,10 @@ class CompiledRSNN:
                                                    state.pre)
             core, logits, aux = self._compose_step(state.rsnn, x_hat,
                                                    ff0=pre)
-            prop = mask.sum(axis=1)
-            aux = dict(aux, delta_propagated=prop,
-                       delta_skipped=x_t.shape[1] - prop)
-            return (DeltaRSNNState(rsnn=core, x_prev=x_hat, pre=pre),
-                    logits, aux)
-        return self._compose_step(state, x_t)
+        prop = mask.sum(axis=1)
+        aux = dict(aux, delta_propagated=prop,
+                   delta_skipped=x_t.shape[1] - prop)
+        return DeltaRSNNState(rsnn=core, x_prev=x_hat, pre=pre), logits, aux
 
     def _compose_step(self, state: RSNNState, x_t: jax.Array,
                       ff0: jax.Array | None = None):
@@ -497,7 +507,8 @@ class CompiledRSNN:
         semantics are sequential either way, so a C-frame chunk is
         bit-identical to C single-frame steps."""
         if self.ops.megastep is not None:
-            return self.ops.megastep(state, x_chunk, self._lif)
+            with jax.default_matmul_precision(MATMUL_PRECISION):
+                return self.ops.megastep(state, x_chunk, self._lif)
 
         def body(st, x_t):
             st, logits, aux = self._frame_step(st, x_t)
@@ -944,6 +955,13 @@ class StreamLoop(SlotScheduler):
                         ("v2-chunk-quiet", b, c, self.ring_frames),
                         eng._loop_step_ring_chunk_quiet, st, x, ctrl, ring)
         self._warm_slot_ops()
+
+    @property
+    def step_executable(self):
+        """The step this loop dispatches: after ``aot_warmup`` the
+        compiled executable (``as_text()``, ``memory_analysis()``), else
+        the jitted function."""
+        return self._fn_ring if self.pipeline_depth >= 1 else self._fn_step
 
     def _warm_slot_ops(self) -> None:
         """Touch the per-slot-index eager helpers once per slot: each

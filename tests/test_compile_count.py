@@ -6,14 +6,14 @@ regression was a ``ring[i, :fill]`` harvest slice baking every (slot,
 length) pair into its own executable) shows up here as a nonzero compile
 count instead of as multi-ms p99 outliers in the load generator.
 
-Counting uses jax's internal monitoring events (every lowering/compile
-records ``/jax/compilation_cache/compile_requests_use_cache``; cached
+Counting uses ``jax.monitoring`` events (every lowering/compile records
+``/jax/compilation_cache/compile_requests_use_cache``; cached
 executable-cache hits record nothing), cross-checked against the engine's
 own ``compile_count`` of AOT builds."""
 
 import numpy as np
 import pytest
-from jax._src import monitoring
+from jax import monitoring
 
 from repro.core import rsnn
 from repro.serving import stream as S
@@ -35,7 +35,7 @@ class _CompileListener:
         return self
 
     def __exit__(self, *exc):
-        monitoring._unregister_event_listener_by_callback(self)
+        monitoring.unregister_event_listener(self)
 
 
 def _utts(cfg, lens, seed=3):

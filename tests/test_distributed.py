@@ -22,8 +22,10 @@ from repro.distributed import sharding as shd
 
 
 def _run_subprocess(code: str) -> str:
+    # the parent may hold the accelerator; the child stays on the CPU
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+           "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src",
+           "PATH": "/usr/bin:/bin"}
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, cwd=".",
                          timeout=420)
@@ -98,8 +100,7 @@ def test_compressed_psum_multidevice():
         from repro.distributed.compression import compressed_psum
         mesh = jax.make_mesh((8,), ("d",))
         x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 32)), jnp.float32)
-        from jax.experimental.shard_map import shard_map
-        f = shard_map(lambda x: compressed_psum(x[0], "d")[None],
+        f = jax.shard_map(lambda x: compressed_psum(x[0], "d")[None],
                       mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
         got = np.asarray(f(x))
         want = np.asarray(x.sum(0))

@@ -1,12 +1,14 @@
 """Event-driven spike-broadcast kernels vs oracle + bit-identity properties.
 
-The central contract: the gather-accumulate over compacted ascending-index
-spike-event lists is BIT-IDENTICAL to the dense matmul on the same input
-(``np.testing.assert_array_equal``, not allclose) — the accumulate runs as
-one dot over the event axis, reproducing the dense dot's partial-sum
-sequence on the sequential-reduction regime (contraction depth <= ~384;
-H here is 16..256).  ``hypothesis`` is optional (try-import); a
-deterministic density sweep keeps the property running on bare installs.
+The central contract: the matmul over each row's event list is
+BIT-IDENTICAL to the dense matmul on the same input
+(``np.testing.assert_array_equal``, not allclose).  It holds by
+construction: the accumulate over the kept events is the dense dot over
+the row with the truncated tail zeroed, so no tolerance is needed at any
+precision.  The finite-queue truncation is also checked against a numpy
+loop over integer codes, whose sums are exact in any order.
+``hypothesis`` is optional (try-import); a deterministic density sweep
+keeps the property running on bare installs.
 """
 
 import dataclasses
@@ -84,6 +86,23 @@ def test_kernel_matches_oracle_under_overflow():
     # lossless capacity == dense, even via the explicit capacity arg
     out = ops.spike_broadcast(x, w, capacity=64)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x @ w))
+
+
+@pytest.mark.parametrize("capacity", [1, 5, 17, 64])
+def test_event_queue_matches_numpy_loop_on_int_codes(capacity):
+    """Independent reference: keep each row's first ``capacity`` events in
+    a Python loop, then an integer matmul.  Int4 codes times spike counts
+    sum exactly in f32, so the kernel must match bit for bit."""
+    rng = np.random.default_rng(capacity)
+    x = (rng.random((24, 64)) < 0.4) * rng.integers(1, 3, (24, 64))
+    w = rng.integers(-8, 8, (64, 40))
+    kept = np.zeros_like(x)
+    for r in range(x.shape[0]):
+        cols = np.flatnonzero(x[r])[:capacity]
+        kept[r, cols] = x[r, cols]
+    got = ops.spike_broadcast(jnp.asarray(x, jnp.float32),
+                              jnp.asarray(w, jnp.float32), capacity=capacity)
+    np.testing.assert_array_equal(np.asarray(got), (kept @ w).astype(np.float32))
 
 
 def test_merged_union_path():
