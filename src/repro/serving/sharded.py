@@ -245,14 +245,20 @@ class ShardedStreamLoop(StreamLoop):
         self._buf = self._buf.at[i, : len(req.frames)].set(
             jnp.asarray(req.frames, jnp.float32))
 
-    def _dispatch_step(self, active: np.ndarray):
+    def _gather_host_frames(self) -> None:
+        return None  # frames are gathered on device from the pinned buffer
+
+    def _stage_chunk(self, counts: list[int]) -> None:
+        return None
+
+    def _dispatch_step(self, x: None, active: np.ndarray):
         pos = jax.device_put(np.asarray(self.slot_pos, np.int32), self._slot)
         act = jax.device_put(active, self._slot)
         self.state, logits, aux_vec = self._fn_step(
             self.state, self._buf, pos, act)
         return np.asarray(logits), aux_vec
 
-    def _dispatch_ring_step(self, ctrl: np.ndarray) -> None:
+    def _dispatch_ring_step(self, x: None, ctrl: np.ndarray) -> None:
         word = np.empty((3, self.slots), np.int32)
         word[0] = self.slot_pos
         word[1:] = ctrl  # [active mask; ring idx] from the base loop
@@ -271,15 +277,14 @@ class ShardedStreamLoop(StreamLoop):
         return (np.asarray(self.slot_pos, np.int32)[None, :]
                 + np.arange(self.chunk_frames, dtype=np.int32)[:, None])
 
-    def _dispatch_step_chunk(self, counts: list[int], act: np.ndarray):
+    def _dispatch_step_chunk(self, x: None, act: np.ndarray):
         pos = jax.device_put(self._chunk_cursors(), self._ctrl)
         actd = jax.device_put(act, self._ctrl)
         self.state, logits, aux_vec = self._fn_step(
             self.state, self._buf, pos, actd)
         return np.asarray(logits), aux_vec
 
-    def _dispatch_ring_chunk(self, counts: list[int],
-                             ctrl: np.ndarray) -> None:
+    def _dispatch_ring_chunk(self, x: None, ctrl: np.ndarray) -> None:
         word = np.empty((3, self.chunk_frames, self.slots), np.int32)
         word[0] = self._chunk_cursors()
         word[1:] = ctrl  # [fill mask; ring idx] from the base loop

@@ -85,6 +85,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import complexity, rsnn, sparse, spike_ops
 from repro.core import lif as lif_lib
@@ -837,6 +838,12 @@ class StreamLoop(SlotScheduler):
     frames_served`` exposes the 1 -> 1/C amortization under full slots.
     ``track_sparsity=False`` detaches the sparsity-counter sink entirely:
     no counter math, no counter fetches.
+
+    The step path records profiler spans (``rsnn.step``, ``rsnn.refill``,
+    ``rsnn.complete``, ``rsnn.egress``, ...; docs/serving.md, "Tracing a
+    serving loop"), which cost nothing unless ``jax.profiler`` is tracing,
+    and counts at the same boundaries: ``refills``, ``completions``,
+    ``watermark_flushes``, ``egress_bytes`` and ``egress_valid_bytes``.
     """
 
     def __init__(self, engine: CompiledRSNN, batch_slots: int = 4,
@@ -981,7 +988,8 @@ class StreamLoop(SlotScheduler):
         the handle: the *next* dispatch donates (deletes) it, and blocking
         on a deleted buffer raises — the slice owns its own buffer and
         becomes ready exactly when the step's ring output does."""
-        return self._ring[0, 0, 0]
+        with TraceAnnotation("rsnn.fence"):
+            return self._ring[0, 0, 0]
 
     # ------------------------------------------------------------- frontend
 
@@ -1025,11 +1033,19 @@ class StreamLoop(SlotScheduler):
         completion — ring rows are dead once harvested, so the new stream
         may overwrite them while those blocks are still in flight.)"""
         req.t_start = self.clock()
+        self.refills += 1
         self._flushed[i] = 0
-        self.state = reset_slot(self.state, i)
+        self._reset_slot(i, req)
+
+    def _reset_slot(self, i: int, req: StreamRequest) -> None:
+        """Zero slot ``i``'s recurrent state, at ``req``'s boundary (its
+        refill or its completion): one eager scatter per state leaf."""
+        with TraceAnnotation("rsnn.reset_slot", sid=req.sid, slot=i):
+            self.state = reset_slot(self.state, i)
 
     def _finish_slot(self, i: int) -> StreamRequest:
         req = super()._finish_slot(i)
+        self.completions += 1
         req.t_done = self.clock()
         if self.pipeline_depth == 0:
             # synchronous contract: logits were fetched this step, so the
@@ -1039,9 +1055,10 @@ class StreamLoop(SlotScheduler):
 
     # ------------------------------------------------------------ step path
 
-    def _gather_host_frames(self) -> np.ndarray:
+    def _gather_host_frames(self) -> np.ndarray | None:
         """Host-side frame assembly: idle slots carry zero frames (the
-        counter masking keys off the active mask, not this zeroing)."""
+        counter masking keys off the active mask, not this zeroing).
+        (The sharded loop gathers on device and returns None.)"""
         d = self.engine.cfg.input_dim
         x = np.zeros((self.slots, d), np.float32)
         for i, r in enumerate(self.slot_req):
@@ -1049,22 +1066,20 @@ class StreamLoop(SlotScheduler):
                 x[i] = r.frames[self.slot_pos[i]]
         return x
 
-    def _dispatch_step(self, active: np.ndarray):
+    def _dispatch_step(self, x: np.ndarray, active: np.ndarray):
         """v1 path: advance the engine one frame over all slots through the
         donated (and, with ``aot_warmup``, pre-compiled) step — input
         quantization fused into the dispatch, state updated in place.
         Returns (logits (slots, fc_dim) np, packed masked counter
         vector)."""
-        x = self._gather_host_frames()
         self.state, logits, aux_vec = self._fn_step(self.state, x, active)
         return np.asarray(logits), aux_vec
 
-    def _dispatch_ring_step(self, ctrl: np.ndarray) -> None:
+    def _dispatch_ring_step(self, x: np.ndarray, ctrl: np.ndarray) -> None:
         """v2 path: dispatch one pipelined step (no host transfer; input
         quantization is fused into the jitted step, all scalar operands
         ride the packed ``ctrl`` word).  The state, ring, and counter
         accumulator are donated — XLA writes the ring row in place."""
-        x = self._gather_host_frames()
         if self.counters is None:
             self.state, self._ring = self._fn_ring(
                 self.state, x, ctrl, self._ring)
@@ -1075,33 +1090,47 @@ class StreamLoop(SlotScheduler):
     def step_once(self) -> bool:
         """One engine step over all slots; returns False when fully drained
         (empty queue, empty slots, and — in the pipelined contract — an
-        empty in-flight pipeline)."""
-        self._refill()
-        active = self.active_mask()
-        if not active.any():
-            if self._inflight:  # shutdown drain: retire without dispatching
-                self._retire()
-                return True
-            return False
-        if self.pipeline_depth == 0:
-            if self.chunk_frames == 1:
-                return self._step_once_sync(active)
-            return self._step_once_sync_chunk()
-        if self.chunk_frames > 1:
-            return self._step_once_chunk()
+        empty in-flight pipeline).  Its phases are profiler spans
+        (``rsnn.step`` and its children; docs/serving.md)."""
+        with TraceAnnotation("rsnn.step"):
+            with TraceAnnotation("rsnn.refill"):
+                self._refill()
+            active = self.active_mask()
+            if not active.any():
+                if self._inflight:  # shutdown drain: retire, no dispatch
+                    self._retire()
+                    return True
+                return False
+            if self.pipeline_depth == 0:
+                if self.chunk_frames == 1:
+                    return self._step_once_sync(active)
+                return self._step_once_sync_chunk()
+            if self.chunk_frames > 1:
+                return self._step_once_chunk()
 
-        ctrl = np.zeros((2, self.slots), np.int32)  # [active mask; ring idx]
-        ctrl[0] = active
-        ctrl[1] = [self.slot_pos[i] - self._flushed[i]
-                   if self.slot_req[i] is not None else 0
-                   for i in range(self.slots)]
-        self._dispatch_ring_step(ctrl)
+            with TraceAnnotation("rsnn.assemble"):
+                x = self._gather_host_frames()
+                ctrl = np.zeros((2, self.slots), np.int32)  # [mask; ring idx]
+                ctrl[0] = active
+                ctrl[1] = [self.slot_pos[i] - self._flushed[i]
+                           if self.slot_req[i] is not None else 0
+                           for i in range(self.slots)]
+            with TraceAnnotation("rsnn.dispatch"):
+                self._dispatch_ring_step(x, ctrl)
+            return self._after_ring_dispatch([1] * self.slots,
+                                             int(active.sum()))
+
+    def _after_ring_dispatch(self, counts: list[int], served: int) -> bool:
+        """Pipelined bookkeeping of a step just dispatched that advanced
+        each occupied slot by ``counts[i]`` frames, ``served`` in all:
+        count it, advance the slots, queue its fence, and retire down to
+        ``pipeline_depth - 1`` steps in flight."""
         self.steps += 1
         self.dispatches += 1
-        self.frames_served += int(active.sum())
+        self.frames_served += served
         if self.counters is not None:
-            self._frames_acc += float(active.sum())
-        completed = self._advance_slots()
+            self._frames_acc += float(served)
+        completed = self._advance_slots(counts)
         self._inflight.append(_InflightStep(self._ring_fence(), completed))
         while len(self._inflight) > max(self.pipeline_depth - 1, 0):
             self._retire()
@@ -1134,10 +1163,11 @@ class StreamLoop(SlotScheduler):
             counts.append(n)
         return counts
 
-    def _stage_chunk(self, counts: list[int]) -> np.ndarray:
+    def _stage_chunk(self, counts: list[int]) -> np.ndarray | None:
         """Host-side chunk staging: the next ``counts[i]`` frames of each
         slot into an (F, slots, input_dim) buffer; idle sub-steps stay
-        zero (the fill mask, not this zeroing, keys the counters)."""
+        zero (the fill mask, not this zeroing, keys the counters).  (The
+        sharded loop gathers on device and returns None.)"""
         x = np.zeros((self.chunk_frames, self.slots, self.engine.cfg.input_dim),
                      np.float32)
         for i, r in enumerate(self.slot_req):
@@ -1146,10 +1176,9 @@ class StreamLoop(SlotScheduler):
                 x[:counts[i], i] = r.frames[p:p + counts[i]]
         return x
 
-    def _dispatch_step_chunk(self, counts: list[int], act: np.ndarray):
+    def _dispatch_step_chunk(self, x: np.ndarray, act: np.ndarray):
         """v1 chunked dispatch: (F, slots) fill mask ``act`` -> (logits
         (F, slots, fc_dim) np, packed masked counter vector)."""
-        x = self._stage_chunk(counts)
         self.state, logits, aux_vec = self._fn_step(self.state, x, act)
         return np.asarray(logits), aux_vec
 
@@ -1157,11 +1186,14 @@ class StreamLoop(SlotScheduler):
         """v1 synchronous contract at ``chunk_frames > 1``: one dispatch
         and one logit fetch per chunk, scheduling otherwise identical to
         per-frame stepping."""
-        counts = self._chunk_counts()
-        act = np.zeros((self.chunk_frames, self.slots), bool)
-        for i, n in enumerate(counts):
-            act[:n, i] = True
-        logits_np, aux_vec = self._dispatch_step_chunk(counts, act)
+        with TraceAnnotation("rsnn.assemble"):
+            counts = self._chunk_counts()
+            act = np.zeros((self.chunk_frames, self.slots), bool)
+            for i, n in enumerate(counts):
+                act[:n, i] = True
+            x = self._stage_chunk(counts)
+        with TraceAnnotation("rsnn.dispatch"):
+            logits_np, aux_vec = self._dispatch_step_chunk(x, act)
         self.host_syncs += 1  # per-chunk logit fetch
         self.steps += 1
         self.dispatches += 1
@@ -1179,14 +1211,12 @@ class StreamLoop(SlotScheduler):
             self.slot_pos[i] += counts[i]
             if self.slot_pos[i] == len(r.frames):
                 self._finish_slot(i)
-                self.state = reset_slot(self.state, i)
+                self._reset_slot(i, r)
         return True
 
-    def _dispatch_ring_chunk(self, counts: list[int],
-                             ctrl: np.ndarray) -> None:
+    def _dispatch_ring_chunk(self, x: np.ndarray, ctrl: np.ndarray) -> None:
         """v2 chunked dispatch (no host transfer): ``ctrl`` is the packed
         (2, F, slots) word of ``_ring_chunk_step_fused``."""
-        x = self._stage_chunk(counts)
         if self.counters is None:
             self.state, self._ring = self._fn_ring(
                 self.state, x, ctrl, self._ring)
@@ -1197,93 +1227,93 @@ class StreamLoop(SlotScheduler):
     def _step_once_chunk(self) -> bool:
         """v2 pipelined contract at ``chunk_frames > 1``: one in-flight
         pipeline entry per chunk."""
-        counts = self._chunk_counts()
-        c, b = self.chunk_frames, self.slots
-        ctrl = np.zeros((2, c, b), np.int32)
-        # default ring index is one past the end: idle sub-steps' writes
-        # are dropped (mode="drop" in _ring_write_chunk)
-        ctrl[1] = self.ring_frames
-        for i, n in enumerate(counts):
-            if n:
-                base = self.slot_pos[i] - self._flushed[i]
-                ctrl[0, :n, i] = 1
-                ctrl[1, :n, i] = base + np.arange(n)
-        self._dispatch_ring_chunk(counts, ctrl)
-        self.steps += 1
-        self.dispatches += 1
-        served = int(sum(counts))
-        self.frames_served += served
-        if self.counters is not None:
-            self._frames_acc += float(served)
-        completed = self._advance_slots_chunk(counts)
-        self._inflight.append(_InflightStep(self._ring_fence(), completed))
-        while len(self._inflight) > max(self.pipeline_depth - 1, 0):
-            self._retire()
-        return True
+        with TraceAnnotation("rsnn.assemble"):
+            counts = self._chunk_counts()
+            c, b = self.chunk_frames, self.slots
+            ctrl = np.zeros((2, c, b), np.int32)
+            # default ring index is one past the end: idle sub-steps'
+            # writes are dropped (mode="drop" in _ring_write_chunk)
+            ctrl[1] = self.ring_frames
+            for i, n in enumerate(counts):
+                if n:
+                    base = self.slot_pos[i] - self._flushed[i]
+                    ctrl[0, :n, i] = 1
+                    ctrl[1, :n, i] = base + np.arange(n)
+            x = self._stage_chunk(counts)
+        with TraceAnnotation("rsnn.dispatch"):
+            self._dispatch_ring_chunk(x, ctrl)
+        return self._after_ring_dispatch(counts, int(sum(counts)))
 
-    def _advance_slots_chunk(self, counts: list[int]) -> list[StreamRequest]:
-        """``_advance_slots`` generalized to a per-slot frame count (the
-        chunk's fill): cursors advance by ``counts[i]``; completion and
-        the ring watermark are decided at the chunk boundary.  ``counts``
-        is capped by remaining ring capacity, so fill never exceeds
-        ``ring_frames``."""
+    def _advance_slots(self, counts: list[int]) -> list[StreamRequest]:
+        """Dispatch-time bookkeeping: advance each occupied slot's cursor
+        by ``counts[i]`` frames (one per step, or the chunk's fill),
+        harvest completed or watermark-full slots, and return the
+        completed requests.  Completion depends only on host-side frame
+        counts, so this is safe to run while the step is still in flight
+        — the schedule is identical to the synchronous contract's."""
         completed = []
         for i, r in enumerate(self.slot_req):
-            if r is None or counts[i] == 0:
-                continue
-            self.slot_pos[i] += counts[i]
-            fill = self.slot_pos[i] - self._flushed[i]
-            if self.slot_pos[i] == len(r.frames):  # stream complete
-                if fill > 0:
-                    r.pending.append((self._ring[i], fill))
+            if r is not None and counts[i] and self._advance_slot(
+                    i, r, counts[i]):
                 completed.append(r)
-                self._finish_slot(i)
-                self._flushed[i] = 0
-                self.state = reset_slot(self.state, i)
-            elif fill == self.ring_frames:  # watermark flush: ring is full
-                r.pending.append((self._ring[i], fill))
-                self._flushed[i] = self.slot_pos[i]
         return completed
 
-    def _advance_slots(self) -> list[StreamRequest]:
-        """Dispatch-time bookkeeping: advance cursors, harvest completed or
-        watermark-full slots (a lazy device slice of the ring — the fetch
-        happens at retire time), reset + free finished slots.  Completion
-        depends only on host-side frame counts, so this is safe to run
-        while the step is still in flight — the schedule is identical to
-        the synchronous contract's."""
-        completed = []
-        for i, r in enumerate(self.slot_req):
-            if r is None:
-                continue
-            self.slot_pos[i] += 1
-            fill = self.slot_pos[i] - self._flushed[i]
-            if self.slot_pos[i] == len(r.frames):  # stream complete
-                if fill > 0:
-                    r.pending.append((self._ring[i], fill))
-                completed.append(r)
+    def _advance_slot(self, i: int, r: StreamRequest, n: int) -> bool:
+        """Advance slot ``i`` by ``n`` frames.  On completion, or when the
+        ring row is full (watermark flush), slice the row for harvest (a
+        lazy device slice — the fetch happens at retire time); on
+        completion also free and reset the slot.  ``n`` is capped by the
+        remaining ring capacity, so fill never exceeds ``ring_frames``.
+        Returns whether ``r`` completed."""
+        self.slot_pos[i] += n
+        fill = self.slot_pos[i] - self._flushed[i]
+        done = self.slot_pos[i] == len(r.frames)
+        if not done and fill != self.ring_frames:
+            return False
+        with TraceAnnotation("rsnn.complete", sid=r.sid, slot=i):
+            if fill > 0:
+                r.pending.append((self._ring[i], fill))
+            if done:
                 self._finish_slot(i)
                 self._flushed[i] = 0
-                self.state = reset_slot(self.state, i)
-            elif fill == self.ring_frames:  # watermark flush: ring is full
-                r.pending.append((self._ring[i], fill))
+                self._reset_slot(i, r)
+            else:  # watermark flush: the ring row is full
+                self.watermark_flushes += 1
                 self._flushed[i] = self.slot_pos[i]
-        return completed
+        return done
 
     def _retire(self) -> None:
         """Retire the oldest in-flight step: fence on its completion, then
         materialize the logit blocks of streams it completed."""
-        step = self._inflight.popleft()
-        if step.handle is not None:
-            jax.block_until_ready(step.handle)  # fence, not a transfer
-        for r in step.completed:
+        with TraceAnnotation("rsnn.retire"):
+            step = self._inflight.popleft()
+            if step.handle is not None:
+                with TraceAnnotation("rsnn.fence_wait"):
+                    jax.block_until_ready(step.handle)  # fence, not a transfer
+            for r in step.completed:
+                self._egress(r)
+                r.t_harvest = self.clock()
+
+    def _egress(self, r: StreamRequest) -> None:
+        """Fetch ``r``'s pending logit blocks to the host, counting the
+        bytes that cross (whole ring rows) and the valid ones in them."""
+        nbytes = valid = 0
+        for block, fill in r.pending:
+            nbytes += block.nbytes
+            valid += fill * block.shape[-1] * block.dtype.itemsize
+        with TraceAnnotation("rsnn.egress", sid=r.sid, bytes=nbytes,
+                             valid_bytes=valid):
             self.host_syncs += r._materialize()
-            r.t_harvest = self.clock()
+        self.egress_bytes += nbytes
+        self.egress_valid_bytes += valid
 
     def _step_once_sync(self, active: np.ndarray) -> bool:
         """v1 synchronous contract: fetch logits (and counters, when a sink
         is attached) to the host every step."""
-        logits_np, aux_vec = self._dispatch_step(active)
+        with TraceAnnotation("rsnn.assemble"):
+            x = self._gather_host_frames()
+        with TraceAnnotation("rsnn.dispatch"):  # and the logit fetch
+            logits_np, aux_vec = self._dispatch_step(x, active)
         self.host_syncs += 1  # per-frame logit fetch
         self.steps += 1
         self.dispatches += 1
@@ -1301,7 +1331,7 @@ class StreamLoop(SlotScheduler):
             self.slot_pos[i] += 1
             if self.slot_pos[i] == len(r.frames):
                 self._finish_slot(i)
-                self.state = reset_slot(self.state, i)
+                self._reset_slot(i, r)
         return True
 
     @property
@@ -1345,6 +1375,12 @@ class StreamLoop(SlotScheduler):
         self.host_syncs = 0
         self.dispatches = 0  # jitted device dispatches (1/chunk, not 1/frame)
         self.frames_served = 0  # slot-frames advanced across all dispatches
+        # at the boundaries of the step path's profiler spans (rsnn.*)
+        self.refills = 0  # requests placed into a slot
+        self.completions = 0  # requests that left their slot, done
+        self.watermark_flushes = 0  # ring rows sliced before completion
+        self.egress_bytes = 0  # logit-block bytes fetched at retire
+        self.egress_valid_bytes = 0  # of them, the frames' valid rows
 
     def _drain_aux(self) -> None:
         """Fold the device-side counter accumulator into ``counters`` (one
