@@ -89,9 +89,10 @@ def init_params(key: jax.Array, cfg: RSNNConfig) -> dict:
 def init_state(cfg: RSNNConfig, batch: int, num_ts: int | None = None) -> RSNNState:
     ts = num_ts or cfg.num_ts
     h = cfg.hidden_dim
-    z = jnp.zeros((ts, batch, h), cfg.dtype)
+    # one buffer per leaf: the slot loops donate every leaf of the state
     return RSNNState(
-        h0=z, h1=z,
+        h0=jnp.zeros((ts, batch, h), cfg.dtype),
+        h1=jnp.zeros((ts, batch, h), cfg.dtype),
         lif0=lif_lib.init_lif_state(batch, h, cfg.dtype),
         lif1=lif_lib.init_lif_state(batch, h, cfg.dtype),
     )

@@ -30,7 +30,7 @@ engine out:
     Quantization is elementwise with a static scale, so the front-end is
     bit-transparent.
 
-Scheduling (queue order, refill-at-step-start, reset-on-finish, pipeline
+Scheduling (queue order, refill-at-step-start, reset-on-refill, pipeline
 retirement) is *inherited* from ``StreamLoop`` — only the data path is
 overridden — and the jitted step wraps the same ``_frame_step``, so logits
 are identical to the single-device loop on the same utterance set
@@ -105,6 +105,11 @@ class ShardedStreamLoop(StreamLoop):
         self._buf = jax.device_put(
             jnp.zeros((slots, max_frames, engine.cfg.input_dim), jnp.float32),
             NamedSharding(self.mesh, shd.stream_ring_spec()))
+        # the slot reset keeps the state's placement: mask on the slot
+        # sharding, every output leaf where its input leaf lives
+        self._jit_reset = jax.jit(
+            engine._reset_slots, donate_argnums=(0,),
+            out_shardings=jax.tree.map(lambda a: a.sharding, self.state))
         # the loop-carried buffers (state, and for the pipelined contract
         # the ring + counter accumulator) are donated so their updates are
         # in-place; the pinned frame buffer is read-only in-step and reused
@@ -309,6 +314,18 @@ class ShardedStreamLoop(StreamLoop):
             self._fn_step = self._jit_chunk_step
             self._fn_ring = (self._jit_ring_chunk if self.track_sparsity
                              else self._jit_ring_chunk_quiet)
+        self._fn_reset = self._jit_reset
+
+    def _compile_reset(self):
+        """The slot reset compiled against the placed state; like the
+        sharded steps it lives on the loop."""
+        exe = self._jit_reset.lower(
+            self.state, self._slot_mask(np.zeros(self.slots, bool))).compile()
+        self.engine.compile_count += 1
+        return exe
+
+    def _slot_mask(self, mask: np.ndarray):
+        return jax.device_put(mask, self._slot)
 
     def _warm_executables(self) -> None:
         """AOT-compile the sharded step this loop dispatches.  The jits

@@ -13,9 +13,10 @@ Lifecycle (contract v2 — pipelined)
    calibration), so chunked streaming is bit-identical to a one-shot pass.
 2. **Slots.** ``StreamLoop`` packs N concurrent utterances into a fixed
    decode batch of ``batch_slots`` slots.  Every engine step advances each
-   active slot by one frame; a finished slot has its recurrent state zeroed
-   (``reset_slot``) and is refilled from the queue without stopping the
-   batch — continuous batching with membrane potentials instead of KV rows.
+   active slot by one frame; a finished slot is refilled from the queue
+   without stopping the batch, its recurrent state zeroed (``reset_slot``)
+   as the next utterance takes it — continuous batching with membrane
+   potentials instead of KV rows.
 3. **State.** ``CompiledRSNN`` carries ``RSNNState`` (per-ts spikes + LIF
    membrane chain) across frames; parity with ``core.rsnn.forward`` over the
    concatenated utterance is the engine's correctness contract
@@ -172,21 +173,37 @@ class DeltaRSNNState(NamedTuple):
     pre: jax.Array  # (B, hidden_dim) cached x_hat @ l0_wx
 
 
-def reset_slot(state, i: int):
-    """Zero one slot's recurrent state (fresh utterance boundary)."""
+def reset_slot(state, slots):
+    """Zero the recurrent state of ``slots`` (fresh utterance boundary):
+    one slot index, or a (B,) bool mask.  Traced with the mask as an
+    operand, one executable zeroes any set of slots (the slot loops'
+    compiled reset)."""
     if isinstance(state, DeltaRSNNState):
         # delta carries reset with the core state: a fresh utterance must
         # not inherit the previous occupant's held inputs/pre-activations
-        return DeltaRSNNState(rsnn=reset_slot(state.rsnn, i),
-                              x_prev=state.x_prev.at[i].set(0.0),
-                              pre=state.pre.at[i].set(0.0))
+        return DeltaRSNNState(rsnn=reset_slot(state.rsnn, slots),
+                              x_prev=_zero_slots(state.x_prev, slots, 0),
+                              pre=_zero_slots(state.pre, slots, 0))
 
     def zl(s: LIFState) -> LIFState:
-        return LIFState(u=s.u.at[i].set(0.0), spike=s.spike.at[i].set(0.0))
+        return LIFState(u=_zero_slots(s.u, slots, 0),
+                        spike=_zero_slots(s.spike, slots, 0))
 
-    return RSNNState(h0=state.h0.at[:, i].set(0.0),
-                     h1=state.h1.at[:, i].set(0.0),
+    return RSNNState(h0=_zero_slots(state.h0, slots, 1),
+                     h1=_zero_slots(state.h1, slots, 1),
                      lif0=zl(state.lif0), lif1=zl(state.lif1))
+
+
+def _zero_slots(x: jax.Array, slots, axis: int) -> jax.Array:
+    """``x`` with the ``slots`` entries of its slot axis ``axis`` zeroed
+    (``slots``: an index or a bool mask over that axis)."""
+    b = x.shape[axis]
+    mask = jnp.asarray(slots)
+    if mask.dtype != jnp.bool_:
+        mask = jnp.arange(b) == mask
+    shape = [1] * x.ndim
+    shape[axis] = b
+    return jnp.where(mask.reshape(shape), jnp.zeros((), x.dtype), x)
 
 
 class CompiledRSNN:
@@ -298,6 +315,7 @@ class CompiledRSNN:
             self._ring_chunk_step_fused, donate_argnums=(0, 3, 4))
         self._loop_step_ring_chunk_quiet = jax.jit(
             self._ring_chunk_step_fused_quiet, donate_argnums=(0, 3))
+        self._loop_reset = jax.jit(self._reset_slots, donate_argnums=(0,))
         # AOT executable cache (jax.jit(...).lower().compile() results),
         # shared by every loop over this engine; ``compile_count`` moves
         # only on a real build, so the compile-count regression test can
@@ -619,6 +637,11 @@ class CompiledRSNN:
         return self._ring_chunk_step_quiet(
             state, self._quantize_in_graph(x_raw), ring, ctrl[1])
 
+    def _reset_slots(self, state, mask: jax.Array):
+        """The slot loops' reset: the module's ``reset_slot`` (looked up
+        when traced) over a (slots,) bool mask."""
+        return reset_slot(state, mask)
+
     # ------------------------------------------------------------ execution
 
     def step(self, state: RSNNState, x_q: jax.Array):
@@ -796,9 +819,16 @@ class StreamLoop(SlotScheduler):
 
     N submitted utterances share a fixed decode batch of ``batch_slots``
     rows.  Each ``step_once`` advances every active slot by one frame; a
-    slot whose utterance ends is state-reset and refilled from the queue
-    mid-batch, so throughput never drops to the shortest stream.  Idle slots
-    carry zero frames and are excluded from the sparsity counters.
+    slot whose utterance ends is refilled from the queue mid-batch, so
+    throughput never drops to the shortest stream.  Idle slots carry zero
+    frames and are excluded from the sparsity counters.
+
+    A slot's recurrent state is zeroed when its next occupant is placed:
+    every slot ``_refill`` fills in a step is reset by ONE compiled
+    dispatch that takes the slots as a (slots,) bool mask operand, before
+    the step is assembled.  Until then a freed slot's state is stale and
+    never read — its counters are masked and its ring row was sliced for
+    harvest at completion.
 
     ``pipeline_depth`` selects the step-lifecycle contract (module
     docstring): ``0`` is the v1 synchronous loop (one logit + one counter
@@ -816,7 +846,7 @@ class StreamLoop(SlotScheduler):
     length is not a multiple of C) — no mid-chunk refill; completions,
     refills, and the ring watermark are decided at the chunk boundary,
     and idle sub-steps are masked out of the ring writes and the counters
-    while the completing slot's state is reset at the boundary — so
+    while the next occupant's state is reset at its refill — so
     per-stream logits, final state, and counters are bit-identical to
     ``chunk_frames=1``, which remains the bit-parity comparator the same
     way ``pipeline_depth=0`` is.  The pipelined contract requires
@@ -843,7 +873,9 @@ class StreamLoop(SlotScheduler):
     ``rsnn.complete``, ``rsnn.egress``, ...; docs/serving.md, "Tracing a
     serving loop"), which cost nothing unless ``jax.profiler`` is tracing,
     and counts at the same boundaries: ``refills``, ``completions``,
-    ``watermark_flushes``, ``egress_bytes`` and ``egress_valid_bytes``.
+    ``watermark_flushes``, ``egress_bytes`` and ``egress_valid_bytes``;
+    ``reset_dispatches`` counts the compiled slot resets, so ``refills /
+    reset_dispatches`` is the slots one reset zeroes.
     """
 
     def __init__(self, engine: CompiledRSNN, batch_slots: int = 4,
@@ -863,7 +895,7 @@ class StreamLoop(SlotScheduler):
             # a live stream's ring fill advances in whole chunks, so with
             # ring_frames % chunk_frames == 0 its capacity at a chunk
             # boundary is never less than a full chunk and only *completed*
-            # (state-reset) slots ever idle mid-chunk.  A non-multiple ring
+            # (freed) slots ever idle mid-chunk.  A non-multiple ring
             # would force a live slot to idle mid-chunk on ring-capacity,
             # advancing its recurrent state through zero frames it never
             # received — silently breaking chunk/per-frame bit parity.
@@ -883,6 +915,7 @@ class StreamLoop(SlotScheduler):
         self._flushed = [0] * batch_slots  # frames already harvested, per slot
         self._inflight: collections.deque[_InflightStep] = collections.deque()
         self._ring = self._init_ring() if pipeline_depth >= 1 else None
+        self._to_reset = np.zeros(batch_slots, bool)  # filled this refill
         self.reset_metrics()
         self._bind_step_fns()
         if aot_warmup:
@@ -914,6 +947,7 @@ class StreamLoop(SlotScheduler):
             self._fn_step = eng._loop_step_masked_chunk
             self._fn_ring = (eng._loop_step_ring_chunk if self.track_sparsity
                              else eng._loop_step_ring_chunk_quiet)
+        self._fn_reset = eng._loop_reset
 
     def _warm_executables(self) -> None:
         """AOT-compile the step executable this loop dispatches
@@ -971,16 +1005,30 @@ class StreamLoop(SlotScheduler):
         return self._fn_ring if self.pipeline_depth >= 1 else self._fn_step
 
     def _warm_slot_ops(self) -> None:
-        """Touch the per-slot-index eager helpers once per slot: each
-        static slot index bakes its own tiny executable (``reset_slot``'s
-        scatter, the ring-row harvest slice, the retire fence slice), so
-        warming them here keeps mid-serve compiles at zero."""
-        for i in range(self.slots):
-            jax.block_until_ready(reset_slot(self.state, i))
-            if self._ring is not None:
-                jax.block_until_ready(self._ring[i])
+        """Build the one slot-reset executable, and touch the eager ring
+        helpers: each static slot index bakes its own tiny ring-row
+        harvest slice, and the retire fence slice one more, so warming
+        them here keeps mid-serve compiles at zero."""
+        self._fn_reset = self._compile_reset()
         if self._ring is not None:
+            for i in range(self.slots):
+                jax.block_until_ready(self._ring[i])
             jax.block_until_ready(self._ring_fence())
+
+    def _compile_reset(self):
+        """AOT-compile the slot reset over this loop's state and a
+        (slots,) mask, in the engine's keyed cache: one executable per
+        slot count, whatever slots it zeroes.  (Overridden by the sharded
+        loop, which compiles against its placed state.)"""
+        eng, sds = self.engine, jax.ShapeDtypeStruct
+        st = jax.tree.map(lambda a: sds(a.shape, a.dtype), self.state)
+        return eng.aot_compile(("reset", self.slots), eng._loop_reset, st,
+                               sds((self.slots,), jnp.bool_))
+
+    def _slot_mask(self, mask: np.ndarray):
+        """The reset's (slots,) mask operand (overridden to place it with
+        the slot sharding)."""
+        return mask
 
     def _ring_fence(self):
         """A tiny eager slice of the just-dispatched ring, used as the
@@ -1026,22 +1074,27 @@ class StreamLoop(SlotScheduler):
             self.queue.append(req)
         return sid
 
+    def _refill(self) -> None:
+        """Fill free slots from the queue, then zero the recurrent state of
+        every slot just filled in one compiled dispatch."""
+        super()._refill()
+        if self._to_reset.any():
+            mask, self._to_reset = self._to_reset, np.zeros(self.slots, bool)
+            self.state = self._fn_reset(self.state, self._slot_mask(mask))
+            self.reset_dispatches += 1
+
     def _on_slot_filled(self, i: int, req: StreamRequest) -> None:
-        """Fresh utterance boundary: zero the slot's recurrent state and
-        harvest cursor.  (The previous occupant's un-materialized logit
-        blocks, if any, were already sliced out of the ring at its
-        completion — ring rows are dead once harvested, so the new stream
-        may overwrite them while those blocks are still in flight.)"""
+        """Fresh utterance boundary: zero the slot's harvest cursor and
+        mark its state for ``_refill``'s reset.  (The previous occupant's
+        un-materialized logit blocks, if any, were already sliced out of
+        the ring at its completion — ring rows are dead once harvested, so
+        the new stream may overwrite them while those blocks are still in
+        flight.)"""
         req.t_start = self.clock()
         self.refills += 1
         self._flushed[i] = 0
-        self._reset_slot(i, req)
-
-    def _reset_slot(self, i: int, req: StreamRequest) -> None:
-        """Zero slot ``i``'s recurrent state, at ``req``'s boundary (its
-        refill or its completion): one eager scatter per state leaf."""
         with TraceAnnotation("rsnn.reset_slot", sid=req.sid, slot=i):
-            self.state = reset_slot(self.state, i)
+            self._to_reset[i] = True
 
     def _finish_slot(self, i: int) -> StreamRequest:
         req = super()._finish_slot(i)
@@ -1142,13 +1195,14 @@ class StreamLoop(SlotScheduler):
         """Frames each slot serves in this chunk: bounded by the chunk
         size and the stream's remaining frames (ragged tail).  A slot that
         completes idles to the chunk boundary (no mid-chunk refill) with
-        its sub-steps masked from the ring and the counters; its state is
-        reset at the boundary, so the idle advance is invisible.  In the
-        pipelined contract a live slot never idles: ``ring_frames`` is a
-        multiple of ``chunk_frames`` (constructor invariant), so fill
-        advances in whole chunks, hits the watermark exactly at a chunk
-        boundary, and the flush restores full capacity — which is also why
-        a stream longer than ``ring_frames`` never deadlocks."""
+        its sub-steps masked from the ring and the counters; its next
+        occupant's state is reset at refill, so the idle advance is
+        invisible.  In the pipelined contract a live slot never idles:
+        ``ring_frames`` is a multiple of ``chunk_frames`` (constructor
+        invariant), so fill advances in whole chunks, hits the watermark
+        exactly at a chunk boundary, and the flush restores full capacity
+        — which is also why a stream longer than ``ring_frames`` never
+        deadlocks."""
         counts = []
         for i, r in enumerate(self.slot_req):
             if r is None:
@@ -1211,7 +1265,6 @@ class StreamLoop(SlotScheduler):
             self.slot_pos[i] += counts[i]
             if self.slot_pos[i] == len(r.frames):
                 self._finish_slot(i)
-                self._reset_slot(i, r)
         return True
 
     def _dispatch_ring_chunk(self, x: np.ndarray, ctrl: np.ndarray) -> None:
@@ -1262,7 +1315,7 @@ class StreamLoop(SlotScheduler):
         """Advance slot ``i`` by ``n`` frames.  On completion, or when the
         ring row is full (watermark flush), slice the row for harvest (a
         lazy device slice — the fetch happens at retire time); on
-        completion also free and reset the slot.  ``n`` is capped by the
+        completion also free the slot.  ``n`` is capped by the
         remaining ring capacity, so fill never exceeds ``ring_frames``.
         Returns whether ``r`` completed."""
         self.slot_pos[i] += n
@@ -1276,7 +1329,6 @@ class StreamLoop(SlotScheduler):
             if done:
                 self._finish_slot(i)
                 self._flushed[i] = 0
-                self._reset_slot(i, r)
             else:  # watermark flush: the ring row is full
                 self.watermark_flushes += 1
                 self._flushed[i] = self.slot_pos[i]
@@ -1331,7 +1383,6 @@ class StreamLoop(SlotScheduler):
             self.slot_pos[i] += 1
             if self.slot_pos[i] == len(r.frames):
                 self._finish_slot(i)
-                self._reset_slot(i, r)
         return True
 
     @property
@@ -1381,6 +1432,7 @@ class StreamLoop(SlotScheduler):
         self.watermark_flushes = 0  # ring rows sliced before completion
         self.egress_bytes = 0  # logit-block bytes fetched at retire
         self.egress_valid_bytes = 0  # of them, the frames' valid rows
+        self.reset_dispatches = 0  # compiled slot resets (one per refill)
 
     def _drain_aux(self) -> None:
         """Fold the device-side counter accumulator into ``counters`` (one

@@ -1,10 +1,11 @@
 """Zero steady-state compiles: after loop construction (which AOT-warms
-the step executables and the per-slot eager helpers), serving MUST NOT
-trigger any new XLA compilation.  This guards the compile-storm class of
-bug permanently: a shape- or index-dependent op on the hot path (the PR-6
-regression was a ``ring[i, :fill]`` harvest slice baking every (slot,
-length) pair into its own executable) shows up here as a nonzero compile
-count instead of as multi-ms p99 outliers in the load generator.
+the step and slot-reset executables and the per-slot eager ring helpers),
+serving MUST NOT trigger any new XLA compilation.  This guards the
+compile-storm class of bug permanently: a shape- or index-dependent op
+on the hot path (the PR-6 regression was a ``ring[i, :fill]`` harvest
+slice baking every (slot, length) pair into its own executable) shows up
+here as a nonzero compile count instead of as multi-ms p99 outliers in
+the load generator.
 
 Counting uses ``jax.monitoring`` events (every lowering/compile records
 ``/jax/compilation_cache/compile_requests_use_cache``; cached
@@ -104,14 +105,15 @@ def test_aot_cache_shared_across_loops(engine):
 
 def test_aot_warmup_counts_builds(engine):
     """Distinct step signatures build distinct executables, visible in the
-    engine's compile_count (the executable-cache counter assertion)."""
+    engine's compile_count (the executable-cache counter assertion); a
+    new slot count builds one slot-reset executable beside its step."""
     before = engine.compile_count
     S.StreamLoop(engine, batch_slots=3, pipeline_depth=2,
                  ring_frames=12, chunk_frames=3)
-    assert engine.compile_count == before + 1
+    assert engine.compile_count == before + 2  # step + reset
     S.StreamLoop(engine, batch_slots=3, pipeline_depth=2,
                  ring_frames=12, chunk_frames=4)  # new chunk -> new build
-    assert engine.compile_count == before + 2
+    assert engine.compile_count == before + 3  # same slots: reset reused
 
 
 def test_opt_out_still_serves(engine, small_cfg):

@@ -40,7 +40,7 @@ PARENTS = {
     "rsnn.assemble": {"rsnn.step"},
     "rsnn.dispatch": {"rsnn.step"},
     "rsnn.complete": {"rsnn.step"},
-    "rsnn.reset_slot": {"rsnn.refill", "rsnn.complete"},
+    "rsnn.reset_slot": {"rsnn.refill"},
     "rsnn.fence": {"rsnn.step"},
     "rsnn.retire": {"rsnn.step", None},  # None: flush() after the run
     "rsnn.fence_wait": {"rsnn.retire"},
@@ -98,10 +98,9 @@ def check_spans(spans, counts, sids):
         if name in ("rsnn.complete", "rsnn.reset_slot"):
             assert "slot" in stats
     per = {n: [s for m, s, _ in spans if m == n] for n in names}
-    # one reset per refill and per completion; one egress per request
+    # one reset per refill, none at completion; one egress per request
     resets = [p for n, _, p in spans if n == "rsnn.reset_slot"]
-    assert resets.count("rsnn.refill") == counts["refills"]
-    assert resets.count("rsnn.complete") == counts["completions"]
+    assert resets == ["rsnn.refill"] * counts["refills"]
     assert len(per["rsnn.complete"]) == (counts["completions"]
                                          + counts["watermark_flushes"])
     assert sorted(s["sid"] for s in per["rsnn.egress"]) == sorted(sids)
@@ -121,6 +120,13 @@ def engine(small_cfg, rng_key):
                                   S.EngineConfig(input_scale=scale)), utts
 
 
+def check_reset_dispatches(dispatches, refills, steps):
+    """One compiled reset per refill that placed a slot: at least one, and
+    no more than the slots placed or the steps taken."""
+    assert 1 <= dispatches <= min(refills, steps), (dispatches, refills,
+                                                    steps)
+
+
 def _serve(loop, utts):
     sids = [loop.submit(u) for u in utts]
     done = loop.run()
@@ -137,6 +143,7 @@ def test_spans_and_counters_of_the_pipelined_loop(engine, tmp_path, chunk):
     counts = expected_counts(LENS, RING, FC_BYTES)
     assert {k: getattr(loop, k) for k in counts} == counts
     check_spans(program_spans(str(tmp_path)), counts, set(sids))
+    check_reset_dispatches(loop.reset_dispatches, loop.refills, loop.steps)
 
     plain = S.StreamLoop(make(), batch_slots=2, pipeline_depth=2,
                          ring_frames=RING, chunk_frames=chunk)
@@ -151,9 +158,11 @@ def test_reset_metrics_zeroes_the_counters(engine):
     loop = S.StreamLoop(make(), batch_slots=2, ring_frames=RING)
     _serve(loop, utts)
     assert loop.watermark_flushes > 0 and loop.egress_bytes > 0
+    assert loop.reset_dispatches > 0
     loop.reset_metrics()
     assert [loop.refills, loop.completions, loop.watermark_flushes,
-            loop.egress_bytes, loop.egress_valid_bytes] == [0] * 5
+            loop.egress_bytes, loop.egress_valid_bytes,
+            loop.reset_dispatches] == [0] * 6
 
 
 _SHARDED = """
@@ -185,13 +194,14 @@ def serve(traced):
     counts = {k: getattr(loop, k) for k in (
         "refills", "completions", "watermark_flushes", "egress_bytes",
         "egress_valid_bytes")}
-    return sids, {r.sid: r.stacked_logits() for r in done}, counts
+    resets = [loop.reset_dispatches, loop.refills, loop.steps]
+    return sids, {r.sid: r.stacked_logits() for r in done}, counts, resets
 
-sids, a, counts = serve(True)
-_, b, counts_b = serve(False)
+sids, a, counts, resets = serve(True)
+_, b, counts_b, _ = serve(False)
 same = all(np.array_equal(a[s], b[s]) for s in sids)
 print(json.dumps({"sids": sids, "counts": counts, "counts_untraced":
-                  counts_b, "same": same}))
+                  counts_b, "resets": resets, "same": same}))
 """
 
 
@@ -209,4 +219,5 @@ def test_spans_and_counters_of_the_sharded_loop(tmp_path):
     counts = expected_counts(lens, RING, FC_BYTES)
     assert got["counts"] == counts and got["counts_untraced"] == counts
     assert got["same"]
+    check_reset_dispatches(*got["resets"])
     check_spans(program_spans(str(tmp_path)), counts, set(got["sids"]))
