@@ -8,7 +8,10 @@ float rounding: at most about 5e-7 on a TPU v5 lite.  Three bfloat16 passes
 per dot leave 2e-6 to 6e-6 on nearly every frame.
 A frame is off when its error exceeds ``FRAME_TOL``, between the two, or
 when it never reached the host.  The number compared is ``off_share``: the
-share of compared frames that are off.
+share of compared frames that are off.  This is the comparison of a family
+whose reference module supplies no ``compare`` of its own; an outcome of
+either kind gives ``numbers``, each held to the cell's limit of that name
+(``limits/<cell>.json``), and ``describe()``, a line of diagnostics.
 
 A spike is a threshold of the membrane, so a membrane within rounding
 distance of the threshold fires on one side and not on the other, and the
@@ -54,6 +57,17 @@ class Outcome:
         e = self.errors[self.errors <= ATOL_REL]
         return ([float(np.quantile(e, q)) for q in (0.5, 0.9, 0.99, 1.0)]
                 if e.size else [])
+
+    @property
+    def numbers(self) -> dict:
+        return {"off_share": self.off_share}
+
+    def describe(self) -> str:
+        return (f"utterances={self.utterances} frames={self.frames}"
+                f" diverged_frames={self.diverged} utterances_diverged="
+                f"{self.utterances_diverged} first_divergence="
+                f"{sorted(self.first_divergence)[:12]} "
+                f"agreeing_error_quantiles={self.agreeing_quantiles()}")
 
 
 def frame_errors(got: np.ndarray, want: np.ndarray) -> np.ndarray:

@@ -1,9 +1,9 @@
-"""The measured window: traffic from a ``Plan`` into the program's
-``StreamLoop``, timed on the host clock.
+"""The measured window: traffic from a ``Plan`` into the served loop that
+the configuration's model family builds (a ``ServedLoop``; for the RSNN the
+program's ``StreamLoop``), timed on the host clock.
 
-The window drives ``StreamLoop.submit`` -> ``step_once`` (the fused
-mega-step and the pipelined logit ring) -> the harvested logits that
-``StreamRequest.stacked_logits`` returns, which the check compares.
+The window drives ``submit`` -> ``step_once`` -> the logits that the
+request's ``stacked_logits`` returns, which the check compares.
 
 Every number the window reports is the harness's own: slot-frames, the
 requests due or done in the window, and when their logits reached the
@@ -17,13 +17,57 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+from typing import Protocol, Sequence, Sized, runtime_checkable
 
 import jax
+import numpy as np
 
 from bench.lib import traffic
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+@runtime_checkable
+class ServedRequest(Protocol):
+    """One request as the loop serves it (the RSNN's ``StreamRequest``)."""
+
+    sid: int  # what ``ServedLoop.submit`` returned for it
+    logits: Sequence  # its logit rows on the host so far, one per frame
+
+    def stacked_logits(self) -> np.ndarray:
+        """All its logit rows, (frames, classes), fetched if need be."""
+
+
+@runtime_checkable
+class ServedLoop(Protocol):
+    """What the harness drives and reads of a model family's served loop
+    (the RSNN's ``StreamLoop``).  A step advances every request that holds
+    a slot by one frame; a request takes a slot in the step that first
+    advances it and is out of ``slot_req`` after the step that advances its
+    last frame (the ``Tracker`` counts slot-frames by this)."""
+
+    queue: Sized  # submitted requests waiting for a slot
+    slot_req: Sequence  # per slot, the ServedRequest that holds it or None
+    finished: list  # completed requests
+    frames_served: int  # the loop's own count of slot-frames (diagnostic)
+    pipeline_depth: int  # steps in flight before their logits land
+
+    def submit(self, frames: np.ndarray) -> int:
+        """Queue an utterance; returns its ``sid``."""
+
+    def step_once(self) -> bool:
+        """Refill free slots and advance one frame; False when it had
+        nothing to dispatch."""
+
+    def flush(self) -> None:
+        """Land the logits of every step in flight."""
+
+    def run(self) -> list:
+        """Serve until the queue and the slots are empty."""
+
+    def reset_metrics(self) -> None:
+        """Zero the loop's counters (``frames_served`` among them)."""
 
 
 class CompileCounter:
@@ -85,7 +129,7 @@ class Seen:
     """One request as the harness saw it, on the harness's clock.
 
     ``handle`` is the program's request; of it the harness reads only its
-    place in the slot table (``StreamLoop.slot_req``) and the logit rows
+    place in the slot table (``ServedLoop.slot_req``) and the logit rows
     it holds on the host (``logits``)."""
 
     __slots__ = ("req", "sid", "handle", "t_submit", "t_due", "step_start",
@@ -114,7 +158,7 @@ class Tracker:
     in a step is in the table after it, and one that leaves was in it
     before (no utterance is one frame long)."""
 
-    def __init__(self, loop, clock):
+    def __init__(self, loop: ServedLoop, clock):
         self.loop, self.clock = loop, clock
         self.seen: dict = {}  # sid -> Seen, every request submitted
         self.steps = 0  # step_once calls that advanced a frame
@@ -200,8 +244,8 @@ def _finish(tracker: Tracker, measured: list) -> tuple:
     return unfinished, off
 
 
-def closed_loop(loop, plan: traffic.Plan, seconds: float, host: Host,
-                counter: CompileCounter) -> Window:
+def closed_loop(loop: ServedLoop, plan: traffic.Plan, seconds: float,
+                host: Host, counter: CompileCounter) -> Window:
     """A queue that never runs dry: slots start with residual-life tails
     and the queue is topped up to ``plan.queue_floor`` after every step.
     The window measures the requests that left their slot in it."""
@@ -250,8 +294,8 @@ def closed_loop(loop, plan: traffic.Plan, seconds: float, host: Host,
                   off)
 
 
-def open_loop(loop, plan: traffic.Plan, seconds: float, host: Host,
-              counter: CompileCounter) -> Window:
+def open_loop(loop: ServedLoop, plan: traffic.Plan, seconds: float,
+              host: Host, counter: CompileCounter) -> Window:
     """Arrivals at their due times from the generator's start; the window
     opens after the pre-roll, and the load stays on after it closes until
     every request due in it has its logits on the host (or the drain

@@ -4,8 +4,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
-import importlib
 import json
 import os
 import shutil
@@ -15,7 +15,7 @@ import time
 import jax
 import numpy as np
 
-from bench.lib import check, drive, model, spec, stats, traffic
+from bench.lib import check, drive, spec, stats, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -47,7 +47,7 @@ def enable_cache() -> str:
 WARM_UTTERANCES = 4
 
 
-def warm(loop, plan: traffic.Plan) -> None:
+def warm(loop: drive.ServedLoop, plan: traffic.Plan) -> None:
     """Serve a few short utterances, so that the step, refill, reset,
     harvest and retire have all run; then forget them.  (The loop built
     every per-slot executable when it was made.)"""
@@ -68,14 +68,6 @@ class Run:
     window: drive.Window
     reduction: object  # trace.DeviceReduction, or None without --trace 1
     peaks: dict  # this device kind's row of peaks.json
-
-    @property
-    def model(self) -> dict:
-        return self.cell.config["model"]
-
-    @property
-    def compression(self) -> dict:
-        return self.cell.config["compression"]
 
     @property
     def frames_per_s(self) -> float:
@@ -141,19 +133,21 @@ class Measured:
     setup_counts: tuple  # (compiles, cache loads) before the window
 
 
-def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
-            accel, cpu, counter: drive.CompileCounter, peaks: dict,
-            t_start: float) -> Measured:
-    """Build, warm, run the window, and check it against the reference."""
+def measure(bench: spec.Bench, cell: spec.Cell, seed: int, seconds: float,
+            trace: bool, accel, cpu, counter: drive.CompileCounter,
+            peaks: dict, t_start: float) -> Measured:
+    """Build, warm, run the window, and check it against the reference
+    (the reference module's ``compare``, else ``check.compare``)."""
     conf, mix = cell.config, cell.traffic
+    family = bench.family(conf["reference"])
     marks = {"start": t_start, "jax": time.monotonic()}
     plan = traffic.Plan(mix, slots=conf["serving"]["slots"], seconds=seconds,
-                        input_dim=conf["model"]["input_dim"],
-                        scale_log2=conf["input"]["scale_log2"], seed=seed)
+                        seed=seed,
+                        make_bank=functools.partial(family.input_bank, conf))
     marks["traffic"] = time.monotonic()
-    params = model.make_params(conf["model"], seed, accel)
+    params = family.make_params(conf, seed, accel)
     marks["weights"] = time.monotonic()
-    loop = model.build_loop(conf, params, accel, cpu)
+    loop = family.build_loop(conf, params, accel, cpu)
     marks["loop"] = time.monotonic()
     warm(loop, plan)
     marks["warm"] = time.monotonic()
@@ -186,40 +180,34 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     del loop
     gc.collect()
     want = reference_logits(conf, params, [x for x, _ in served], cpu,
-                            conf["compute"]["matmul_precision"])
-    outcome = check.compare(served, want)
+                            conf["compute"]["matmul_precision"], bench)
+    compare = getattr(bench.reference(conf["reference"]), "compare",
+                      check.compare)
+    outcome = compare(served, want)
     return Measured(Run(cell, plan, window, reduction, peaks), outcome,
                     served, want, params, marks, memory_peak, setup_counts)
 
 
 def reference_logits(conf: dict, params: dict, utts: list, device,
-                     precision: str) -> dict:
-    """{i: logits} of the configuration's plain reference over ``utts``,
-    every dot at ``precision`` (``compute.matmul_precision`` for the
-    check, the next one below for the control)."""
-    if conf["compute"]["dtype"] != "float32":
-        raise ValueError("the plain reference computes in float32 only, "
-                         f"not {conf['compute']['dtype']!r}")
-    ref = importlib.import_module("bench.reference." + conf["reference"])
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
-        host_params = jax.device_get(params)
-        weights = ref.effective_weights(host_params, conf["compression"])
-        lif = ref.lif_constants(host_params)
-    return dict(ref.logits(utts, weights, lif, conf["model"],
-                           2.0 ** conf["input"]["scale_log2"], precision,
-                           device))
+                     precision: str, bench: spec.Bench | None = None) -> dict:
+    """{i: logits} of the configuration's plain reference
+    (``reference/<conf["reference"]>.py`` under ``bench``'s paths) over
+    ``utts``, from the float weights ``params``, every dot at ``precision``
+    on ``device`` (``compute.matmul_precision`` for the check, the next one
+    below for the control)."""
+    ref = (bench or spec.Bench(ROOT)).reference(conf["reference"])
+    return dict(ref.logits(utts, params, conf, precision, device))
 
 
 def main(argv, t_start: float) -> int:
     args = parse(argv)
-    try:
-        importlib.import_module("repro.serving.stream")
+    bench = spec.Bench(ROOT)
+    cell = bench.cell(args.workload)
+    try:  # a family module imports the program it builds
+        bench.family(cell.config["reference"])
     except ImportError as e:
         say(f"bench: the system under test is missing: {e}")
         return 2
-    bench = spec.Bench(ROOT)
-    cell = bench.cell(args.workload)
     accel = find_accelerator(cell.chips)
     if accel is None:
         say(f"bench: no accelerator with {cell.chips} chip(s): JAX sees "
@@ -229,12 +217,15 @@ def main(argv, t_start: float) -> int:
     cpu = jax.devices("cpu")[0]
     cache = enable_cache()
     counter = drive.CompileCounter()
-    m = measure(cell, args.seed, args.seconds, bool(args.trace), accel, cpu,
-                counter, peaks, t_start)
+    m = measure(bench, cell, args.seed, args.seconds, bool(args.trace),
+                accel, cpu, counter, peaks, t_start)
     run, outcome, window, marks = m.run, m.outcome, m.run.window, m.marks
-    limit = float(cell.limits["off_share"]["limit"])
-    correct = (outcome.off_share <= limit and window.unfinished == 0
-               and window.slot_steps_off == 0 and len(window.measured) > 0)
+    checks = {name: (value, float(cell.limits[name]["limit"]))
+              for name, value in outcome.numbers.items()}
+    checks.update(unfinished=(window.unfinished, 0),
+                  slot_steps_off=(window.slot_steps_off, 0))
+    correct = (all(value <= limit for value, limit in checks.values())
+               and len(window.measured) > 0)
 
     if args.trace:
         metrics = {}
@@ -276,14 +267,9 @@ def main(argv, t_start: float) -> int:
             ["jax", "traffic", "weights", "loop", "warm"]))
         + f" window_open_after_warm={window.t_open - marks['warm']:.3f}s "
         + " ".join(f"{k}={v:.3f}" for k, v in window.prep.items()))
-    say(f"diag check utterances={outcome.utterances} frames={outcome.frames}"
-        f" diverged_frames={outcome.diverged} utterances_diverged="
-        f"{outcome.utterances_diverged} first_divergence="
-        f"{sorted(outcome.first_divergence)[:12]} agreeing_error_quantiles="
-        f"{outcome.agreeing_quantiles()}")
-    say(f"check off_share={outcome.off_share!r} limit={limit!r}")
-    say(f"check unfinished={window.unfinished} limit=0")
-    say(f"check slot_steps_off={window.slot_steps_off} limit=0")
+    say("diag check " + outcome.describe())
+    for name, (value, limit) in checks.items():
+        say(f"check {name}={value!r} limit={limit!r}")
 
     result = {
         "correct": bool(correct),
@@ -300,9 +286,7 @@ def main(argv, t_start: float) -> int:
         result["breakdown"] = {
             "device_ops": [list(kv) for kv in red.top_ops(10)],
             "idle_gaps": [list(g) for g in red.idle_gaps[:10]]}
-    result["check"] = {
-        "off_share": {"value": outcome.off_share, "limit": limit},
-        "unfinished": {"value": window.unfinished, "limit": 0},
-        "slot_steps_off": {"value": window.slot_steps_off, "limit": 0}}
+    result["check"] = {name: {"value": value, "limit": limit}
+                       for name, (value, limit) in checks.items()}
     print(json.dumps(result), flush=True)
     return 0

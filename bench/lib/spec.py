@@ -7,8 +7,12 @@ mix (``<dir>/traffic/<traffic>.json``), checked against the limits in
 where there is none, by the module named for the part of the metric's name
 before its first ``.`` (``device_idle_share.ptt`` and
 ``device_idle_share.backlog`` share ``device_idle_share.py``).
-``<dir>`` is each of the benchmark's ``paths`` in turn, so a later change
-adds a cell, a mix or a metric by adding files.
+A configuration's ``reference`` names its model family: the harness builds
+it with ``<dir>/models/<reference>.py`` (``make_params``, ``input_bank``,
+``build_loop``) and checks it against ``<dir>/reference/<reference>.py``
+(``logits``, and ``compare`` where the family judges its outputs otherwise
+than ``check.compare`` does).  ``<dir>`` is each of the benchmark's ``paths`` in turn, so a
+later change adds a cell, a mix, a metric or a model family by adding files.
 """
 
 from __future__ import annotations
@@ -17,6 +21,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 
 @dataclasses.dataclass
@@ -73,8 +78,30 @@ class Bench:
             path = self._find("metrics", metric + ".py")
         except FileNotFoundError:
             path = self._find("metrics", metric.split(".")[0] + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric.replace(".", "_"), path)
+        return load_module(path).read
+
+    def family(self, name: str):
+        """The model family module ``models/<name>.py``."""
+        return load_module(self._find("models", name + ".py"))
+
+    def reference(self, name: str):
+        """The plain reference module ``reference/<name>.py``."""
+        return load_module(self._find("reference", name + ".py"))
+
+
+def load_module(path: str):
+    """The module at ``path``, loaded once per process: a module loaded
+    again would bring new jitted functions, which compile again."""
+    path = os.path.realpath(path)
+    name = "bench_file_" + "".join(c if c.isalnum() else "_" for c in path)
+    mod = sys.modules.get(name)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(name, path)
         mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return mod

@@ -20,19 +20,19 @@ Two loops:
   window, and the load kept on after it until every request due in the
   window has its logits on the host.
 
-Features are 8-bit fixed-point codes at a power-of-two scale, the format the
-paper's input layer takes: a bank of AR(1) frames drawn from the seed, from
-which each utterance reads a run of frames at its own offset.
+Each utterance reads a run of frames at its own offset from a bank of
+inputs that the configuration's model family makes from the mix's
+``features`` and the seed (``models/<name>.py``, ``input_bank``): an object
+with ``size`` and ``frames(offset, length)``, which wraps round ``size``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 import statistics
+from typing import Callable
 
 import numpy as np
-from scipy.signal import lfilter
 
 _FINE = 100_000  # quantiles that stand for a length distribution
 ORDER = 0x0DE7  # entropy of the one order of lengths and gaps of every run
@@ -77,27 +77,6 @@ def stratified_gaps(rate: float, n: int, span: float) -> np.ndarray:
     return gaps * (span / gaps.sum())
 
 
-class FeatureBank:
-    """AR(1) feature frames on the 8-bit grid, drawn from a seed."""
-
-    def __init__(self, spec: dict, input_dim: int, scale_log2: int,
-                 rng: np.random.Generator):
-        n, a = int(spec["bank_frames"]), float(spec["ar"])
-        e = rng.standard_normal((n, input_dim))
-        x = lfilter([math.sqrt(1.0 - a * a)], [1.0, -a], e, axis=0)
-        codes = np.rint(x * spec["std"] * 2.0 ** -scale_log2)
-        self.codes = np.clip(codes, -128, 127).astype(np.int8)
-        self.scale = 2.0 ** scale_log2
-
-    @property
-    def size(self) -> int:
-        return len(self.codes)
-
-    def frames(self, offset: int, length: int) -> np.ndarray:
-        idx = (offset + np.arange(length)) % self.size
-        return self.codes[idx].astype(np.float32) * np.float32(self.scale)
-
-
 @dataclasses.dataclass
 class Request:
     """One utterance of a run: its frames come from the bank at ``offset``.
@@ -116,7 +95,10 @@ class Plan:
     """The requests of one run of a traffic mix."""
 
     def __init__(self, traffic: dict, *, slots: int, seconds: float,
-                 input_dim: int, scale_log2: int, seed: int):
+                 seed: int,
+                 make_bank: Callable[[dict, np.random.Generator], object]):
+        """``make_bank(traffic["features"], rng)`` is the family's
+        ``input_bank`` for the configuration served."""
         self.traffic = traffic
         self.slots = slots
         self.seconds = float(seconds)
@@ -124,8 +106,8 @@ class Plan:
         deal_ss, feat_ss = ss.spawn(2)
         self.rng = np.random.default_rng(deal_ss)  # offsets, first occupants
         self.order = np.random.default_rng(ORDER)  # lengths and gaps
-        self.bank = FeatureBank(traffic["features"], input_dim, scale_log2,
-                                np.random.default_rng(feat_ss))
+        self.bank = make_bank(traffic["features"],
+                              np.random.default_rng(feat_ss))
         self.lengths = traffic["lengths"]
         self._next_idx = 0
         if traffic["loop"] == "closed":

@@ -1,7 +1,8 @@
 """Host time of one completion or watermark flush at dispatch, in ms: the
 seconds of ``rsnn.complete`` (``StreamLoop._advance_slot``: the ring-row
-slice, the slot's release and, on completion, its eager ``reset_slot``)
-over its count, which is ``completions + watermark_flushes``."""
+slice and, on completion, the slot's release; the slot's state is reset
+when its next occupant is placed, under ``rsnn.refill``) over its count,
+which is ``completions + watermark_flushes``."""
 
 from bench.lib import program_trace
 

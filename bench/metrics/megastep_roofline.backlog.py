@@ -14,7 +14,8 @@ def read(run):
     secs, calls = r.kernel("megastep")
     if calls == 0 or secs <= 0.0:
         return None
+    conf = run.cell.config
     least, _ = work.least_step_seconds(
-        run.model, run.compression, run.cell.config["serving"]["slots"],
+        conf["model"], conf["compression"], conf["serving"]["slots"],
         run.peak_ops(), run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / (secs / calls)

@@ -1,8 +1,9 @@
 """Host time spent filling free slots from the queue, per request placed,
-in ms: the seconds of ``rsnn.refill`` (``SlotScheduler._refill`` in
-``StreamLoop.step_once``, the eager ``reset_slot`` of each new occupant
-included) over the requests it placed, one ``rsnn.reset_slot`` child
-each (the ``refills`` counter's boundary)."""
+in ms: the seconds of ``rsnn.refill`` (``StreamLoop._refill`` in
+``step_once``: the scheduler's refill, which marks each new occupant's
+slot, and the step's one compiled reset dispatch for every slot marked)
+over the requests it placed, one ``rsnn.reset_slot`` child each (the
+``refills`` counter's boundary)."""
 
 from bench.lib import program_trace
 

@@ -9,5 +9,7 @@ from bench.lib import work
 def read(run):
     if run.reduction is None or run.window.frames == 0:
         return None
-    ops = work.ops_per_frame(run.model, run.compression) * run.frames_per_s
+    conf = run.cell.config
+    ops = (work.ops_per_frame(conf["model"], conf["compression"])
+           * run.frames_per_s)
     return 100.0 * ops / run.peak_ops()

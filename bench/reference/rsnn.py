@@ -110,12 +110,27 @@ def _chunk(w, lif, carry, xq, *, num_ts: int, precision: str):
     return jax.lax.scan(frame, carry, xq)
 
 
-def logits(utts: list, weights: dict, lif: dict, model: dict,
-           input_scale: float, precision: str = "highest", device=None):
-    """Yield ``(i, logits (T_i, fc_dim))`` for every utterance ``utts[i]``
-    (raw features, (T_i, input_dim)), each run from a zero state, in blocks
-    of similar length."""
-    device = device or jax.devices("cpu")[0]
+def logits(utts: list, params: dict, config: dict, precision: str,
+           device) -> dict:
+    """{i: logits (T_i, fc_dim)} for every utterance ``utts[i]`` (raw
+    features, (T_i, input_dim)), each run from a zero state, with the
+    weights that ``config`` serves made from the float ``params`` on the
+    host CPU and every dot at ``precision`` on ``device``."""
+    if config["compute"]["dtype"] != "float32":
+        raise ValueError("the plain reference computes in float32 only, "
+                         f"not {config['compute']['dtype']!r}")
+    with jax.default_device(jax.devices("cpu")[0]):
+        host_params = jax.device_get(params)
+        weights = effective_weights(host_params, config["compression"])
+        lif = lif_constants(host_params)
+    return dict(_blocks(utts, weights, lif, config["model"],
+                        2.0 ** config["input"]["scale_log2"], precision,
+                        device))
+
+
+def _blocks(utts: list, weights: dict, lif: dict, model: dict,
+            input_scale: float, precision: str, device):
+    """Yield ``(i, logits)`` for ``logits``, in blocks of similar length."""
     ts, h, d = model["num_ts"], model["hidden_dim"], model["input_dim"]
     qmax = 2.0 ** (model["input_bits"] - 1)
     w = jax.device_put(weights, device)

@@ -87,6 +87,17 @@ def test_configuration_files_are_run_as_stated(conf):
     assert conf["reduced"] == []  # published widths and depth
 
 
+@pytest.mark.parametrize("conf", DOC["configs"], ids=lambda c: c["name"])
+def test_configuration_finds_its_family_and_reference(conf):
+    bench = spec.Bench(tiny.ROOT)
+    with open(os.path.join(tiny.ROOT, conf["file"])) as f:
+        family = json.load(f)["reference"]
+    model = bench.family(family)
+    for name in ("make_params", "build_loop", "input_bank"):
+        assert callable(getattr(model, name)), name
+    assert callable(bench.reference(family).logits)
+
+
 def test_a_full_check_fits_its_time():
     cells = 24  # what later changes may grow the benchmark to
     runs = 2 + 14 * cells

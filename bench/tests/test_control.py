@@ -3,10 +3,12 @@ configuration's (three bfloat16 passes per float32 dot) comes out not
 correct under every cell's limit.  At the configurations' own widths, over
 fewer and shorter utterances than a run compares."""
 
+import functools
+
 import jax
 import pytest
 
-from bench.lib import check, harness, model, spec, traffic
+from bench.lib import check, harness, spec, traffic
 from bench.tests import tiny
 
 BENCH = spec.Bench(tiny.ROOT)
@@ -21,12 +23,13 @@ def test_control_fails_the_cells_limit(name):
                                           max=min(cell.traffic["lengths"]
                                                   ["max"], 160)))
     cpu = jax.devices("cpu")[0]
+    model = BENCH.family(conf["reference"])
     plan = traffic.Plan(mix, slots=conf["serving"]["slots"], seconds=10.0,
-                        input_dim=conf["model"]["input_dim"],
-                        scale_log2=conf["input"]["scale_log2"], seed=11)
+                        seed=11,
+                        make_bank=functools.partial(model.input_bank, conf))
     reqs = plan.first if mix["loop"] == "closed" else plan.arrivals
     utts = [plan.frames(r) for r in reqs[:12]]
-    params = model.make_params(conf["model"], 11, cpu)
+    params = model.make_params(conf, 11, cpu)
     want = harness.reference_logits(conf, params, utts, cpu, "highest")
     same = check.compare([(x, want[i]) for i, x in enumerate(utts)], want)
     assert same.off_share == 0.0

@@ -4,16 +4,18 @@ inputs and which slot serves what."""
 import numpy as np
 import pytest
 
-from bench.lib import traffic
+from bench.lib import spec, traffic
 from bench.tests import tiny
 
 SEEDS = (1, 2**31 + 7, 98765432109)
+RSNN = spec.Bench(tiny.ROOT).family("rsnn")
 
 
 def _plan(name, seed, seconds=10.0, slots=512):
     return traffic.Plan(tiny.load("traffic", name + ".json"), slots=slots,
-                        seconds=seconds, input_dim=40, scale_log2=-5,
-                        seed=seed)
+                        seconds=seconds, seed=seed,
+                        make_bank=lambda features, rng: RSNN.FeatureBank(
+                            features, 40, -5, rng))
 
 
 @pytest.mark.parametrize("name", ["timit_backlog", "commands_backlog"])

@@ -5,8 +5,10 @@ import types
 
 import numpy as np
 
-from bench.lib import drive, traffic
+from bench.lib import drive, spec, traffic
 from bench.tests import tiny
+
+RSNN = spec.Bench(tiny.ROOT).family("rsnn")
 
 
 class Clock:
@@ -75,8 +77,9 @@ def _plan(name, seconds, **lengths):
     mix["features"]["bank_frames"] = 64
     if mix["loop"] == "open":
         mix.update(rate_per_s=50.0, preroll_s=0.2, drain_timeout_s=5.0)
-    return traffic.Plan(mix, slots=4, seconds=seconds, input_dim=2,
-                        scale_log2=-5, seed=7)
+    return traffic.Plan(mix, slots=4, seconds=seconds, seed=7,
+                        make_bank=lambda features, rng: RSNN.FeatureBank(
+                            features, 2, -5, rng))
 
 
 class NoCompiles:

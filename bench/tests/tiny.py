@@ -53,10 +53,15 @@ def tiny_cell(traffic="timit_backlog", config="rsnn_pruned_int4_csc",
     return real
 
 
-def run(monkeypatch, capsys, cell, seed=3, seconds=1.0, trace=0):
+def run(monkeypatch, capsys, cell, seed=3, seconds=1.0, trace=0, root=None):
     """Drive ``harness.main`` over ``cell`` on the CPU -> (exit code, the
-    last stdout line as a dict or None, stderr)."""
+    last stdout line as a dict or None, stderr).  ``root``, where given, is
+    the checkout whose ``BENCHMARK.json`` and ``paths`` the run finds its
+    files in."""
     import jax
+
+    if root is not None:
+        monkeypatch.setattr(harness, "ROOT", str(root))
 
     monkeypatch.setattr(harness, "find_accelerator",
                         lambda chips: jax.devices("cpu")[0])

@@ -38,7 +38,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--control", action="store_true")
     args = ap.parse_args(argv)
-    cell = spec.Bench(ROOT).cell(args.workload)
+    bench = spec.Bench(ROOT)
+    cell = bench.cell(args.workload)
     accel = harness.find_accelerator(cell.chips)
     if accel is None:
         print("readings: no accelerator", file=sys.stderr)
@@ -49,7 +50,7 @@ def main(argv=None) -> int:
     peaks = harness.load_peaks(accel.device_kind)
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.monotonic()
-        m = harness.measure(cell, seed, args.seconds, False, accel, cpu,
+        m = harness.measure(bench, cell, seed, args.seconds, False, accel, cpu,
                             counter, peaks, t0)
         row = {"workload": args.workload, "seed": seed,
                "program": m.outcome.off_share,
@@ -68,7 +69,7 @@ def main(argv=None) -> int:
             t1 = time.monotonic()
             ctrl = harness.reference_logits(
                 cell.config, m.params, [x for x, _ in m.served], accel,
-                "high")
+                "high", bench)
             got = check.compare([(x, ctrl[i]) for i, (x, _) in
                                  enumerate(m.served)], m.reference)
             row.update(control=got.off_share,

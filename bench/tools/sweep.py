@@ -36,7 +36,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--seeds", default="1")
     args = ap.parse_args(argv)
-    cell = spec.Bench(ROOT).cell(args.workload)
+    bench = spec.Bench(ROOT)
+    cell = bench.cell(args.workload)
     accel = harness.find_accelerator(cell.chips)
     if accel is None:
         print("sweep: no accelerator", file=sys.stderr)
@@ -50,7 +51,7 @@ def main(argv=None) -> int:
     for rate, seed in runs:
         cell.traffic["rate_per_s"] = rate
         t0 = time.monotonic()
-        m = harness.measure(cell, seed, args.seconds, False, accel,
+        m = harness.measure(bench, cell, seed, args.seconds, False, accel,
                             cpu, counter, peaks, t0)
         w = m.run.window
         lat = m.run.latencies_ms()
